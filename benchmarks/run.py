@@ -253,11 +253,15 @@ def run_full() -> None:
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="serving benches only, tiny sizes, schema-checked "
                          "(the CI bench-smoke gate)")
-    if ap.parse_args().smoke:
+    args = ap.parse_args()
+    enable_compile_cache()
+    if args.smoke:
         run_smoke()
     else:
         run_full()

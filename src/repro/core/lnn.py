@@ -18,6 +18,7 @@ test).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -111,6 +112,20 @@ def lnn_init(rng, cfg: LNNConfig):
     return params
 
 
+def _f32(fn):
+    """Trace ``fn`` with every float32 matmul at full precision.  On the TPU
+    an f32 matmul otherwise runs as a single bf16 pass, which would put the
+    chip's scores and embeddings far outside float32 agreement with any
+    reference; the CPU backend computes f32 products the same either way."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
 def _apply_towers(params, x, codes):
     """Per-type entity tower: rows whose type code is ``t`` are replaced by
     ``relu(x @ tower_w[t] + tower_b[t])``; rows with code ``-1`` (orders,
@@ -132,6 +147,7 @@ def _apply_towers(params, x, codes):
 # Stage 1 — batch layer
 # ---------------------------------------------------------------------------
 
+@_f32
 def lnn_stage1(params, cfg: LNNConfig, graph: PaddedGraph):
     """Input proj + first L-1 GNN layers.  Returns hidden states [N, H].
 
@@ -158,6 +174,7 @@ def lnn_stage1(params, cfg: LNNConfig, graph: PaddedGraph):
     return h
 
 
+@_f32
 def lnn_order_tower(params, cfg: LNNConfig, order_feats):
     """Stage-1 state of an *order* node, computed locally from raw features.
 
@@ -222,6 +239,7 @@ def _final_hop_aggregate(params, cfg: LNNConfig, h, graph: PaddedGraph):
     return jnp.einsum("ndh,nd->nh", msgs, attn)
 
 
+@_f32
 def lnn_stage2_batch(params, cfg: LNNConfig, h, graph: PaddedGraph):
     """Speed-layer computation over the whole padded graph (training path).
 
@@ -238,6 +256,7 @@ def lnn_stage2_batch(params, cfg: LNNConfig, h, graph: PaddedGraph):
     return _mlp(params, x)
 
 
+@_f32
 def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
                      order_h=None, slot_type=None):
     """Online stage-2 *embedding*: everything up to (but excluding) the MLP
@@ -271,6 +290,7 @@ def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
     return jnp.concatenate([g_out, order_feats], axis=-1)
 
 
+@_f32
 def lnn_stage2_online(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
                       order_h=None, slot_type=None):
     """Online scoring path: KV-fetched entity embeddings -> risk logit.

@@ -4,9 +4,21 @@
     attn        = softmax over valid d  (masked by nbr_mask)
     out[i, :]   = sum_d attn[i,d] * z[nbr_idx[i,d], :]
 
-One grid step owns a node tile and the full feature width (GNN hidden dims
-here are <= 256, so the z gather target fits VMEM whole; the node dimension
-is the tiled axis).  Softmax runs in f32 with the usual max-subtraction.
+The scalar gather ``s_src[nbr_idx]`` is one XLA gather in the wrapper
+(Mosaic lowers only 2-D gathers in-kernel); the kernel then owns the
+softmax and the row aggregation, which it runs as the same weighted one-hot
+``A @ z`` block matmul as ``csr_spmm`` (``A`` built from ``attn`` instead of
+the degree weights).  Grid: (node tiles, node-column blocks), the column
+axis a reduction accumulated in VMEM; softmax is recomputed per column
+block (``bn x D``, negligible next to the one-hot build) in f32 with the
+usual max-subtraction.  GNN hidden dims here are <= 256, so every block
+carries the full feature width.
+
+VMEM budget per program (defaults bn=128, bk=1024, H<=256, D<=64, f32):
+    z block    bk x H     = 1024*256*4 = 1 MiB  (x2 double-buffered)
+    A, iota    bn x bk    = 2 x 512 KiB
+    logits     bn x D     = a few x 32 KiB
+    acc, out   bn x H     = 2 x 128 KiB                   << 16 MiB VMEM
 """
 from __future__ import annotations
 
@@ -15,56 +27,67 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.csr_spmm import masked_rows, onehot_block
 from repro.utils.padding import ceil_div
 
 
-def _edge_softmax_kernel(z_ref, ssrc_ref, sdst_ref, idx_ref, mask_ref, bias_ref, out_ref):
-    z = z_ref[...]                   # [N, H]
-    idx = idx_ref[...]               # [bn, D]
-    mask = mask_ref[...]             # [bn, D]
-    bn, D = idx.shape
+def _make_edge_softmax_kernel(n: int, bk: int):
+    def kernel(z_ref, ssrc_ref, sdst_ref, idx_ref, mask_ref, bias_ref,
+               out_ref, acc_ref):
+        k = pl.program_id(1)
 
-    logits = (
-        jnp.take(ssrc_ref[...], idx, axis=0)
-        + sdst_ref[...][:, None]
-        + bias_ref[...]
-    ).astype(jnp.float32)
-    logits = jnp.where(logits >= 0, logits, 0.2 * logits)          # leaky relu
-    logits = jnp.where(mask > 0, logits, -1e9)
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    e = jnp.exp(logits - m)
-    attn = (e / jnp.sum(e, axis=-1, keepdims=True)) * mask
+        @pl.when(k == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc = jnp.zeros((bn, z.shape[1]), jnp.float32)
+        mask = mask_ref[...]                                   # [bn, D]
+        logits = (ssrc_ref[...] + sdst_ref[...] + bias_ref[...]).astype(
+            jnp.float32)
+        logits = jnp.where(logits >= 0, logits, 0.2 * logits)  # leaky relu
+        logits = jnp.where(mask > 0, logits, -1e9)
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        e = jnp.exp(logits - m)
+        attn = (e / jnp.sum(e, axis=-1, keepdims=True)) * mask
 
-    def body(d, acc):
-        rows = jnp.take(z, idx[:, d], axis=0)
-        return acc + rows.astype(jnp.float32) * attn[:, d][:, None]
+        c0 = k * bk
+        a = onehot_block(idx_ref[...], attn, c0, bk)
+        acc_ref[...] += jnp.dot(a, masked_rows(z_ref, c0, n),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
 
-    acc = jax.lax.fori_loop(0, D, body, acc)
-    out_ref[...] = acc.astype(out_ref.dtype)
+        @pl.when(k == pl.num_programs(1) - 1)
+        def _():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "block_k", "interpret"))
 def edge_softmax_agg_pallas(z, s_src, s_dst, nbr_idx, nbr_mask, etype_bias,
-                            block_n: int = 128, interpret: bool = True):
+                            block_n: int = 128, block_k: int = 1024,
+                            interpret: bool = True):
     n, feat = z.shape
     _, d = nbr_idx.shape
     bn = min(block_n, n)
-    grid = (ceil_div(n, bn),)
+    bk = min(block_k, n)
+    grid = (ceil_div(n, bn), ceil_div(n, bk))
+    ssrc = jnp.take(s_src, nbr_idx, axis=0)                    # [N, D]
+    tile = pl.BlockSpec((bn, d), lambda i, k: (i, 0))
     return pl.pallas_call(
-        _edge_softmax_kernel,
+        _make_edge_softmax_kernel(n, bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, feat), lambda i: (0, 0)),   # z (full)
-            pl.BlockSpec((n,), lambda i: (0,)),          # s_src (full, gathered)
-            pl.BlockSpec((bn,), lambda i: (i,)),         # s_dst tile
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
+            pl.BlockSpec((bk, feat), lambda i, k: (k, 0)),     # z column block
+            tile,                                              # s_src[nbr_idx]
+            pl.BlockSpec((bn, 1), lambda i, k: (i, 0)),        # s_dst
+            tile, tile, tile,                                  # idx, mask, bias
         ],
-        out_specs=pl.BlockSpec((bn, feat), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bn, feat), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, feat), z.dtype),
+        scratch_shapes=[pltpu.VMEM((bn, feat), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(z, s_src, s_dst, nbr_idx, nbr_mask, etype_bias)
+    )(z, ssrc, s_dst[:, None], nbr_idx, nbr_mask, etype_bias)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 from repro.utils.padding import ceil_div
 
 NEG_INF = -1e30
@@ -109,7 +107,7 @@ def flash_attention_pallas(q, k, v, causal: bool = True, window: int | None = No
             pltpu.VMEM((bq,), jnp.float32),      # denominator  l
             pltpu.VMEM((bq, dh), jnp.float32),   # accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 from repro.utils.padding import ceil_div
 
 NEG_INF = -1e30
@@ -99,7 +97,7 @@ def gqa_decode_pallas(q, k, v, kv_len=None, window: int | None = None,
             pltpu.VMEM((rep,), jnp.float32),
             pltpu.VMEM((rep, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

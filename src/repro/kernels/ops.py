@@ -1,7 +1,9 @@
 """Jit'd public wrappers for all kernels, with ref-path dispatch.
 
 ``use_pallas`` routing policy: on TPU the Pallas path compiles natively; on
-CPU (this container) Pallas executes via ``interpret=True``.  Model code
+the CPU backend (the tests' oracle) Pallas executes via ``interpret=True``.
+Any other backend is refused rather than interpreted, so a run that lost
+its chip fails instead of silently timing the interpreter.  Model code
 calls these wrappers; the sharded dry-run uses the ref path (XLA ops) so the
 lowering is backend-independent.
 """
@@ -20,7 +22,14 @@ from repro.kernels.stage2_score import flatten_stage2_params, stage2_score_palla
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on TPU (native Mosaic), True on CPU (interpreter); raises for
+    any other backend."""
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(
+        f"Pallas kernels run natively on 'tpu' or interpreted on 'cpu'; "
+        f"backend {backend!r} is neither")
 
 
 def csr_spmm(h, nbr_idx, weights, block_n: int = 128, block_h: int = 128):

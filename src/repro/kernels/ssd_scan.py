@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, out_ref, state_ref, *, chunk):
     k = pl.program_id(2)
@@ -91,7 +89,7 @@ def ssd_scan_pallas(x, dt, a, b, c, d_skip=None, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b_, h, k_: (b_, k_, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

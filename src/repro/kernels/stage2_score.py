@@ -17,8 +17,10 @@ whole thing in ONE launch over a padded micro-batch:
 The ``[g ; feats]`` concatenation is folded into the MLP's first layer by
 splitting its weight row-wise (``w0[:H]`` / ``w0[H:]``), so no concat ever
 materialises.  Layer counts are static per config, so the tower and MLP
-loops unroll at trace time; the entity-slot aggregation strip-mines over the
-fixed width K exactly like ``csr_spmm.py`` does over the neighbor width.
+loops unroll at trace time, and so does the entity-slot aggregation over the
+fixed width K (Mosaic cannot index a loaded value inside a ``fori_loop``).
+Every matmul is traced at highest precision: the chip's default would run
+an f32 product as one bf16 pass.
 
 Block sizing follows ``stream.microbatch.bucket_size``: the batch dimension
 tiles in power-of-two blocks (capped at ``block_b``), so every micro-batch
@@ -33,7 +35,7 @@ VMEM budget per program (defaults bb=128, K=8, H=64, F=16, f32):
 
 Like the other kernels in this package the same ``pallas_call`` runs in
 interpret mode on CPU (the tier-1 correctness oracle) and compiles natively
-on TPU.
+on TPU (``tests/test_tpu_compile.py`` compiles it for a v5e).
 """
 from __future__ import annotations
 
@@ -67,6 +69,12 @@ def _make_stage2_kernel(gnn_type: str, n_tower: int, n_mlp_extra: int,
     """
 
     def kernel(*refs):
+        # f32 matmuls at full precision: the chip's default runs them as
+        # one bf16 pass (the CPU interpreter is unaffected)
+        with jax.default_matmul_precision("highest"):
+            body(*refs)
+
+    def body(*refs):
         if typed:
             emb_ref, mask_ref, feats_ref, st_ref = refs[0:4]
             woff = 4
@@ -95,7 +103,7 @@ def _make_stage2_kernel(gnn_type: str, n_tower: int, n_mlp_extra: int,
 
         # ---- per-type entity towers (heterogeneous models only) ----
         if typed:
-            st = st_ref[...]                        # [bb, K] int32 codes
+            st = st_ref[...]                        # [bb, K, 1] int32 codes
             ttw = ttw_ref[...]                      # [T, H, H]
             ttb = ttb_ref[...]                      # [T, H]
             emb0 = emb
@@ -103,7 +111,7 @@ def _make_stage2_kernel(gnn_type: str, n_tower: int, n_mlp_extra: int,
                 tr = jnp.maximum(
                     emb0.reshape(bb * K, H) @ ttw[t] + ttb[t], 0.0
                 ).reshape(bb, K, H)
-                emb = jnp.where((st == t)[..., None], tr, emb)
+                emb = jnp.where(st == t, tr, emb)
 
         # ---- order tower: input projection + stage-1 self transforms ----
         h = feats @ w_in_ref[...] + b_in_ref[...] + type_ref[...]
@@ -116,12 +124,9 @@ def _make_stage2_kernel(gnn_type: str, n_tower: int, n_mlp_extra: int,
             cnt = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
             wght = mask / cnt                        # [bb, K]
 
-            def body(k, acc):
-                rows = jax.lax.dynamic_index_in_dim(emb, k, axis=1, keepdims=False)
-                wk = jax.lax.dynamic_index_in_dim(wght, k, axis=1, keepdims=False)
-                return acc + rows * wk[:, None]
-
-            agg = jax.lax.fori_loop(0, K, body, jnp.zeros((bb, H), jnp.float32))
+            agg = jnp.zeros((bb, H), jnp.float32)
+            for k in range(K):                       # static: K is fixed
+                agg = agg + emb[:, k, :] * wght[:, k:k + 1]
             g = h @ w_self_ref[...] + agg @ w_nbr_ref[...]
         else:  # gat: attention over the slots in z-space
             w = w_gat_ref[...]
@@ -135,12 +140,9 @@ def _make_stage2_kernel(gnn_type: str, n_tower: int, n_mlp_extra: int,
             e = jnp.exp(logits - m)
             attn = (e / jnp.sum(e, axis=-1, keepdims=True)) * mask    # [bb, K]
 
-            def body(k, acc):
-                rows = jax.lax.dynamic_index_in_dim(z, k, axis=1, keepdims=False)
-                ak = jax.lax.dynamic_index_in_dim(attn, k, axis=1, keepdims=False)
-                return acc + rows * ak[:, None]
-
-            agg = jax.lax.fori_loop(0, K, body, jnp.zeros((bb, H), jnp.float32))
+            agg = jnp.zeros((bb, H), jnp.float32)
+            for k in range(K):
+                agg = agg + z[:, k, :] * attn[:, k:k + 1]
             g = agg + h @ w_self_ref[...]
         g = jnp.maximum(g + b_last_ref[...], 0.0)
 
@@ -235,8 +237,10 @@ def stage2_score_pallas(entity_emb, emb_mask, order_feats, flat,
     ]
     data = [entity_emb, emb_mask, order_feats]
     if typed:
-        in_specs.append(pl.BlockSpec((bb, k), lambda i: (i, 0)))
-        data.append(slot_type)
+        # codes ride as [B, K, 1] so the per-type select broadcasts over
+        # lanes: Mosaic cannot reshape a [bb, K] mask to [bb, K, 1]
+        in_specs.append(pl.BlockSpec((bb, k, 1), lambda i: (i, 0, 0)))
+        data.append(slot_type[..., None])
     in_specs += [_full(a) for a in flat]
 
     return pl.pallas_call(

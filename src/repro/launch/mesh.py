@@ -28,16 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False, layout: str = "16x16"):
         d, m = (int(x) for x in layout.split("x"))
         assert d * m == 256, f"layout {layout} is not a 256-chip pod"
         shape, axes = (d, m), ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def _auto_axis_types(n: int) -> dict:
-    # jax >= 0.5 wants explicit axis types; 0.4.x has no AxisType at all
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs of the same sharded code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))

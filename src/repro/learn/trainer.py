@@ -237,6 +237,10 @@ class RollingWindowTrainer:
         # process (off the serving GIL); the GBDT head refit stays in the
         # parent — the booster isn't an npz-serializable pytree
         self.in_process = bool(in_process)
+        if self.in_process:
+            from repro.stream.procpool import require_cpu_host
+
+            require_cpu_host("learn.train_in_process=True")
         self._buffer: list = []
         self._since_fire: int | None = None   # None = never fired
         self.stats = {"examples": 0, "fires": 0, "last_window": 0,

@@ -687,6 +687,22 @@ class ProcStoreView:
         self._stats_base = base
 
 
+def require_cpu_host(what: str) -> None:
+    """Refuse to spawn JAX child processes when this process drives a TPU.
+
+    A chip belongs to the one process that opened it, so a child that
+    imports JAX there fails or hangs; pinning the children to the CPU would
+    hide the device instead.  Raised at build time, before any spawn."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} spawns child processes that each import JAX, but this "
+            "process holds the TPU and a chip cannot be shared between "
+            "processes; on a TPU host use workers.backend='inline' and "
+            "learn.train_in_process=False (docs/processes.md)")
+
+
 class ProcessWorkerPool(WorkerPool):
     """The inline :class:`WorkerPool` with its compute plane moved into
     real processes.  Scheduling (queues, triggers, stealing, reorder,
@@ -702,6 +718,7 @@ class ProcessWorkerPool(WorkerPool):
                  service_model_s: float = 0.0, steal_threshold: int | None = None,
                  model_version: int = 0, ring_bytes: int = DEFAULT_RING_BYTES,
                  child_env: dict | None = None):
+        require_cpu_host("workers.backend='process'")
         store_cfg = dict(store_cfg)
         if num_workers > 1:
             if not store_cfg.get("shard_by_entity"):
@@ -1141,5 +1158,6 @@ __all__ = [
     "ShmRing",
     "WorkerDied",
     "pack_frame",
+    "require_cpu_host",
     "unpack_frame",
 ]

@@ -42,7 +42,8 @@ that curve.
 ``async_mode=True`` runs stage 1 on a single background worker thread (the
 batch layer is off the scoring hot path in production); ``drain()`` joins
 outstanding work, and completed futures are pruned on every window-close
-hook so the in-flight list stays bounded over an unbounded stream.  Tests
+hook so the in-flight list stays bounded over an unbounded stream; a
+refresh that raised re-raises at that hook or at ``drain()``.  Tests
 use the default synchronous mode.
 """
 from __future__ import annotations
@@ -162,8 +163,15 @@ class RefreshDriver:
             self.refresh(up_to)
         else:
             # prune completed futures first — over an unbounded stream the
-            # in-flight list must stay bounded between drains
-            self._inflight = [f for f in self._inflight if not f.done()]
+            # in-flight list must stay bounded between drains.  A finished
+            # refresh that raised re-raises here instead of vanishing
+            live = []
+            for f in self._inflight:
+                if f.done():
+                    f.result()
+                else:
+                    live.append(f)
+            self._inflight = live
             # snapshot the ingester state AND the active model on the
             # calling thread (both keep mutating under new events /
             # hot-swaps); only stage 1 + puts go async

@@ -1,4 +1,5 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret=True)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,6 +16,17 @@ RNG = np.random.default_rng(42)
 
 def _tol(dtype):
     return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 else dict(atol=2e-5, rtol=2e-5)
+
+
+def test_interpret_mode_only_on_the_cpu_backend(monkeypatch):
+    """Pallas compiles natively on TPU and is interpreted on the CPU oracle;
+    any other backend is refused instead of silently interpreted."""
+    for backend, interpret in (("tpu", False), ("cpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret() is interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()
 
 
 # ---------------------------------------------------------------------------

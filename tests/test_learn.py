@@ -181,6 +181,16 @@ def test_trainer_rejects_bad_knobs():
         RollingWindowTrainer(cfg, steps=0)
 
 
+def test_train_in_process_refuses_a_tpu_host(monkeypatch):
+    """A spawned fine-tune child would import JAX beside the process that
+    holds the TPU; the trainer refuses at construction instead."""
+    cfg = LNNConfig(num_gnn_layers=2, hidden_dim=4, feat_dim=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        RollingWindowTrainer(cfg, in_process=True)
+    assert RollingWindowTrainer(cfg).in_process is False
+
+
 def _tap_ex(i, *, order_id=None, seq=None, label=0.0, snapshot=0):
     rng = np.random.default_rng(i)
     return TrainingExample(

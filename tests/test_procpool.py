@@ -232,6 +232,22 @@ def test_processpool_requires_entity_affine_shards(proc_world):
             num_workers=2)
 
 
+def test_process_backend_refuses_to_build_on_a_tpu_host(proc_world,
+                                                        monkeypatch):
+    """The serving process holds the TPU; shard processes importing JAX
+    could not get the chip, so the build refuses before any spawn."""
+    _events, cfg, params = proc_world
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ProcessWorkerPool, "_spawn_child",
+                        lambda self, wid: spawned.append(wid))
+    sc = ServiceConfig(model=ModelSection.from_lnn_config(cfg)).replace(
+        workers={"backend": "process"})
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        FraudService(sc, params=params).build()
+    assert spawned == []
+
+
 def test_engine_rejects_injected_store_for_process_backend(proc_world):
     _events, cfg, params = proc_world
     from repro.serve.kvstore import KVStore

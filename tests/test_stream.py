@@ -208,6 +208,29 @@ def test_async_refresh_inflight_list_stays_bounded():
     assert drv._inflight == []
 
 
+def test_failed_async_refresh_surfaces_its_error():
+    """Regression: pruning finished futures used to drop them unread, so a
+    stage-1 refresh that raised on a background thread vanished and the
+    run went on as if the batch layer had written its embeddings."""
+    from concurrent.futures import wait
+
+    drv, ing, _, _ = _tiny_driver(async_mode=True)
+
+    def broken_stage1(pgs, hints, model_version):
+        raise FloatingPointError("stage 1 failed on the device")
+
+    drv.stage1_executor = broken_stage1
+    ing.ingest(_tiny_event(0))
+    res = ing.ingest(_tiny_event(1))               # closes window 0
+    assert drv.on_windows_closed(res.closed_window) is True
+    wait(drv._inflight)                            # ...and its refresh failed
+    res = ing.ingest(_tiny_event(2))
+    with pytest.raises(FloatingPointError, match="stage 1 failed"):
+        drv.on_windows_closed(res.closed_window)
+    with pytest.raises(FloatingPointError, match="stage 1 failed"):
+        drv.drain()
+
+
 def test_refresh_cadence_carries_sparse_window_remainder():
     """Regression: a sparse snapshot jump (+5 windows, refresh_every=2) used
     to reset the counter to 0, silently swallowing the overshoot; the
