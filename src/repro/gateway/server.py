@@ -143,6 +143,7 @@ _SERVICE_SCALARS = [
     ("store_size", "repro_service_store_size", "gauge"),
     ("rollbacks", "repro_service_rollbacks_total", "counter"),
     ("last_good_version", "repro_service_last_good_version", "gauge"),
+    ("compiles", "repro_service_compiles_total", "counter"),
 ]
 
 _SHADOW_SCALARS = [

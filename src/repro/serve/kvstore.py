@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core.hetero import is_typed
 from repro.dist.sharding import rendezvous_shard, stable_shard
-from repro.utils import crashpoint
+from repro.utils import crashpoint, spans
 
 SNAPSHOT_BITS = 20
 MAX_SNAPSHOT = (1 << SNAPSHOT_BITS) - 1
@@ -335,15 +335,17 @@ class KVStore:
         pre-swap embeddings are detectable, not silent.
 
         Returns (emb [B, K, H], mask [B, K], staleness [B, K] int32).
+        The ``kv.lookup`` span (``utils.spans``) covers the call.
         """
-        b = len(entity_t_lists)
-        emb = np.zeros((b, k_max, self.dim), np.float32)
-        mask = np.zeros((b, k_max), np.float32)
-        stale = np.full((b, k_max), -1, np.int32)
-        with self._lock:
-            self._lookup_versioned_into(entity_t_lists, k_max, emb, mask,
-                                        stale, expected_model_version)
-        return emb, mask, stale
+        with spans.span("kv.lookup"):
+            b = len(entity_t_lists)
+            emb = np.zeros((b, k_max, self.dim), np.float32)
+            mask = np.zeros((b, k_max), np.float32)
+            stale = np.full((b, k_max), -1, np.int32)
+            with self._lock:
+                self._lookup_versioned_into(entity_t_lists, k_max, emb, mask,
+                                            stale, expected_model_version)
+            return emb, mask, stale
 
     def _lookup_versioned_into(self, entity_t_lists, k_max, emb, mask, stale,
                                expected_model_version=None):

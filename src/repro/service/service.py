@@ -45,6 +45,7 @@ import numpy as np
 from repro.serve.kvstore import KVStore
 from repro.service.config import ServiceConfig
 from repro.service.types import ScoreRequest, ScoreResponse, ServiceStats
+from repro.utils import spans
 
 
 class ServiceLifecycleError(RuntimeError):
@@ -114,6 +115,7 @@ class FraudService:
         self._autoscaler = None      # streaming (admission.autoscale)
         self._batch_layer = None     # batch
         self._speed_layer = None     # batch
+        self._compiles_at_build: int | None = None
 
     @classmethod
     def from_artifact(cls, path: str, params=None,
@@ -144,6 +146,10 @@ class FraudService:
                 "call load_model() first")
         cfg = self.config
         lnn = cfg.to_lnn_config()
+        # XLA compiles are counted process-wide from here on; stats()
+        # reports those since this build
+        spans.install_compile_counter()
+        self._compiles_at_build = spans.compiles()
         if self.mode == "streaming":
             from repro.stream.engine import StreamingEngine, _stage1_params
 
@@ -683,45 +689,49 @@ class FraudService:
         """Ingest one :class:`~repro.stream.events.CheckoutEvent` and return
         whatever responses completed by its arrival — the legacy engine path
         with the admission controller between ingest and enqueue."""
-        self._ensure(_SERVABLE, "submit")
-        self._require_mode("streaming", "submit")
-        seq = None
-        if self._wal is not None and not self._replaying:
-            # write-ahead: log before any state mutation, so a crash
-            # anywhere inside the apply is repaired by replay, never lost
-            seq = self._wal.append_event("submit", event)
-        self._state = "serving"
-        eng, pool, adm = self._engine, self._engine.pool, self.config.admission
-        now = event.arrival
-        out = pool.poll(now)
-        req = eng.ingest(event)
-        self._acct["requests"] += 1
-        self._acct["in_flight_peak"] = max(
-            self._acct["in_flight_peak"], pool.busy_workers(now))
+        # the "service.submit" span covers the whole call: admission, the
+        # engine's ingest and flushes, and response accounting; its self
+        # time is the facade's own
+        with spans.span("service.submit", order_id=event.order_id):
+            self._ensure(_SERVABLE, "submit")
+            self._require_mode("streaming", "submit")
+            seq = None
+            if self._wal is not None and not self._replaying:
+                # write-ahead: log before any state mutation, so a crash
+                # anywhere inside the apply is repaired by replay, never lost
+                seq = self._wal.append_event("submit", event)
+            self._state = "serving"
+            eng, pool, adm = self._engine, self._engine.pool, self.config.admission
+            now = event.arrival
+            out = pool.poll(now)
+            req = eng.ingest(event)
+            self._acct["requests"] += 1
+            self._acct["in_flight_peak"] = max(
+                self._acct["in_flight_peak"], pool.busy_workers(now))
 
-        if not self._admit(req, pool, adm, now, out):
+            if not self._admit(req, pool, adm, now, out):
+                self._account_scored(out)
+                out.append(ScoreResponse(
+                    request=req, score=math.nan, admitted=False,
+                    model_version=self._model_version))
+                if seq is not None:
+                    self._applied_seq = seq
+                self._maybe_auto_checkpoint()
+                return out
+            # peak records the depth the admitted request actually observed
+            # (post block-drain), so it never exceeds an enforced cap + 1 frame
+            self._acct["queue_depth_peak"] = max(
+                self._acct["queue_depth_peak"], len(pool) + 1)
+            out.extend(pool.submit(req, now))
+            if self._autoscaler is not None:
+                # a scale decision drains the queues; those results were scored
+                # under the old topology and must reach the caller
+                out.extend(self._autoscaler.observe(now))
             self._account_scored(out)
-            out.append(ScoreResponse(
-                request=req, score=math.nan, admitted=False,
-                model_version=self._model_version))
             if seq is not None:
                 self._applied_seq = seq
             self._maybe_auto_checkpoint()
             return out
-        # peak records the depth the admitted request actually observed
-        # (post block-drain), so it never exceeds an enforced cap + 1 frame
-        self._acct["queue_depth_peak"] = max(
-            self._acct["queue_depth_peak"], len(pool) + 1)
-        out.extend(pool.submit(req, now))
-        if self._autoscaler is not None:
-            # a scale decision drains the queues; those results were scored
-            # under the old topology and must reach the caller
-            out.extend(self._autoscaler.observe(now))
-        self._account_scored(out)
-        if seq is not None:
-            self._applied_seq = seq
-        self._maybe_auto_checkpoint()
-        return out
 
     def _admit(self, req, pool, adm, now: float, out: list) -> bool:
         """Admission decision for one streaming request.  Returns False to
@@ -1052,6 +1062,8 @@ class FraudService:
             rollbacks=acct["rollbacks"],
             last_good_version=self._last_good,
         )
+        if self._compiles_at_build is not None:
+            st.compiles = spans.compiles() - self._compiles_at_build
         if self.store is not None:
             st.store_size = len(self.store)
             st.store_stats = dict(self.store.stats)

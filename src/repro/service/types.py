@@ -100,6 +100,7 @@ class ServiceStats:
     store_size: int = 0
     rollbacks: int = 0                      # rollback_model() calls since build
     last_good_version: int | None = None    # rollback target (None = no target)
+    compiles: int = 0                       # XLA compiles in the process since build
     scores_by_version: dict = field(default_factory=dict)  # version -> scored
     shadow: dict = field(default_factory=dict)   # canary/shadow divergence state
     store_stats: dict = field(default_factory=dict)

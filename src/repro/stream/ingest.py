@@ -27,7 +27,7 @@ from dataclasses import dataclass
 from repro.core.dds import DDSGraph, IncrementalDDSBuilder
 from repro.core.partition import IncrementalPartitioner
 from repro.stream.events import CheckoutEvent
-from repro.utils import crashpoint
+from repro.utils import crashpoint, spans
 
 
 @dataclass
@@ -72,24 +72,35 @@ class StreamIngester:
 
     def ingest(self, event: CheckoutEvent) -> IngestResult:
         """Consume one checkout: compute its speed-layer keys, extend the
-        DDS graph, and report any snapshot windows the arrival closed."""
-        crashpoint.fire("ingest.before")
-        t = int(event.snapshot)
-        closed = None
-        if t > self._open_snapshot:
-            if self._open_snapshot >= 0:
-                closed = (self._open_snapshot, t - 1)
-                self.stats["windows_closed"] += t - self._open_snapshot
-            self._open_snapshot = t
-        # keys BEFORE this event activates (entity, t): strictly-past only
-        keys = self.builder.entity_keys(event.entities, t)
-        o = self.builder.add_order(event.entities, t, event.features, event.label)
-        self.partitioner.add_order(event.entities)
-        for ent in event.entities:
-            self._dirty.add((int(ent), t))
-        self.stats["events"] += 1
-        crashpoint.fire("ingest.after")
-        return IngestResult(order_id=o, entity_keys=keys, closed_window=closed)
+        DDS graph, and report any snapshot windows the arrival closed.
+
+        Spans (``utils.spans``): ``ingest.order`` over the call, with
+        ``ingest.keys``, ``ingest.dds`` and ``ingest.partition`` (community
+        update and dirty marks) inside it for its three steps."""
+        with spans.span("ingest.order"):
+            crashpoint.fire("ingest.before")
+            t = int(event.snapshot)
+            closed = None
+            if t > self._open_snapshot:
+                if self._open_snapshot >= 0:
+                    closed = (self._open_snapshot, t - 1)
+                    self.stats["windows_closed"] += t - self._open_snapshot
+                self._open_snapshot = t
+            # keys BEFORE this event activates (entity, t): strictly-past only
+            with spans.span("ingest.keys"):
+                keys = self.builder.entity_keys(event.entities, t)
+            with spans.span("ingest.dds"):
+                o = self.builder.add_order(event.entities, t, event.features,
+                                           event.label)
+            # the community-local refresh's bookkeeping: communities, and
+            # the dirty pairs it drains by community
+            with spans.span("ingest.partition"):
+                self.partitioner.add_order(event.entities)
+                for ent in event.entities:
+                    self._dirty.add((int(ent), t))
+            self.stats["events"] += 1
+            crashpoint.fire("ingest.after")
+            return IngestResult(order_id=o, entity_keys=keys, closed_window=closed)
 
     # ---------------------------------------------------------------- refresh
     def take_refreshable(self, up_to_snapshot: int) -> list:

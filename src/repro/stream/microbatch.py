@@ -32,7 +32,7 @@ import numpy as np
 # leaf module, so this import introduces no package cycle.  ScoredResult is
 # the historical streaming name for the service-wide ScoreResponse.
 from repro.service.types import ScoreRequest, ScoreResponse
-from repro.utils import crashpoint
+from repro.utils import crashpoint, spans
 
 ScoredResult = ScoreResponse
 
@@ -83,7 +83,8 @@ class PendingFlush:
     worker's per-kind flush accounting is unchanged.
     """
 
-    def __init__(self, batcher, batch, n, now, deferred, t0):
+    def __init__(self, batcher, batch, n, now, deferred, t0, seq=0,
+                 kind="forced"):
         self.batcher = batcher
         self.batch = batch
         self.n = n
@@ -91,16 +92,22 @@ class PendingFlush:
         self.deferred = deferred
         self.worker = None          # stamped by the worker that flushed
         self._t0 = t0
+        self.seq = seq
+        self.kind = kind
 
     def __bool__(self) -> bool:
         return True
 
     def resolve(self) -> list:
-        """Block on the reply and build the ScoredResults (parent side)."""
-        probs, staleness, model_version = self.deferred.wait()
-        service = time.perf_counter() - self._t0
-        out = self.batcher._results(self.batch, self.n, self.now, probs,
-                                    staleness, int(model_version), service)
+        """Block on the reply and build the ScoredResults (parent side).
+        Its ``batch.flush`` span carries the same ``seq`` as the one that
+        posted the batch."""
+        with spans.span("batch.flush", seq=self.seq, n=self.n,
+                        trigger=self.kind):
+            probs, staleness, model_version = self.deferred.wait()
+            service = time.perf_counter() - self._t0
+            out = self.batcher._results(self.batch, self.n, self.now, probs,
+                                        staleness, int(model_version), service)
         if self.worker is not None:
             for r in out:
                 r.worker = self.worker
@@ -136,6 +143,7 @@ class MicroBatcher:
         self.clock = clock
         self._queue: list[ScoreRequest] = []
         self._lock = threading.Lock()
+        self._flush_seq = 0         # non-empty flushes popped (span metadata)
         self.stats = {"flushes": 0, "size_flushes": 0, "deadline_flushes": 0,
                       "forced_flushes": 0, "requests": 0, "padded_rows": 0,
                       "empty_flushes": 0, "stolen": 0}
@@ -195,7 +203,7 @@ class MicroBatcher:
             full = len(self._queue) >= self.max_batch
         if not full:
             return []
-        out = self.flush(now)
+        out = self.flush(now, kind="size")
         if out:
             self.stats["size_flushes"] += 1
         return out
@@ -211,13 +219,13 @@ class MicroBatcher:
         dl = self.deadline()
         if dl is None or now < dl:
             return []
-        out = self.flush(dl)
+        out = self.flush(dl, kind="deadline")
         if out:
             self.stats["deadline_flushes"] += 1
         return out
 
     # ------------------------------------------------------------------ flush
-    def flush(self, now: float | None = None):
+    def flush(self, now: float | None = None, kind: str = "forced"):
         """Score everything queued as one padded fixed-shape batch.
 
         Returns the ``ScoredResult`` list, or a :class:`PendingFlush` when
@@ -226,7 +234,14 @@ class MicroBatcher:
 
         The pop is atomic and re-checks emptiness: a concurrent drain (work
         steal, another flush) between the trigger firing and this pop must
-        yield an empty no-op, never a zero-row ``score_fn`` call."""
+        yield an empty no-op, never a zero-row ``score_fn`` call.
+
+        Spans (``utils.spans``): a non-empty flush is one ``batch.flush``
+        span, with the flush's sequence number, real size and trigger
+        ``kind`` (size, deadline or forced) as metadata; inside it
+        ``batch.assemble``, the scorer's own spans, and ``batch.results``.
+        On the process backend the post and the resolve are two
+        ``batch.flush`` spans with the same ``seq``."""
         if now is None:
             now = self.clock()
         with self._lock:
@@ -235,47 +250,52 @@ class MicroBatcher:
                 return []
             batch, self._queue = (self._queue[: self.max_batch],
                                   self._queue[self.max_batch:])
+            self._flush_seq += 1
+            seq = self._flush_seq
         n = len(batch)
-        b = bucket_size(n, self.max_batch)
-        feat_dim = batch[0].features.shape[0]
-        feats = np.zeros((b, feat_dim), np.float32)
-        key_lists: list[list] = [[] for _ in range(b)]
-        for i, r in enumerate(batch):
-            feats[i] = r.features
-            key_lists[i] = list(r.entity_keys)
-        self.stats["padded_rows"] += b - n
+        with spans.span("batch.flush", seq=seq, n=n, trigger=kind):
+            with spans.span("batch.assemble"):
+                b = bucket_size(n, self.max_batch)
+                feat_dim = batch[0].features.shape[0]
+                feats = np.zeros((b, feat_dim), np.float32)
+                key_lists: list[list] = [[] for _ in range(b)]
+                for i, r in enumerate(batch):
+                    feats[i] = r.features
+                    key_lists[i] = list(r.entity_keys)
+            self.stats["padded_rows"] += b - n
 
-        crashpoint.fire("flush.before_score")
-        t0 = time.perf_counter()
-        # scorers may return (probs, staleness) or, when version-aware,
-        # (probs, staleness, model_version) — the version whose jit cache
-        # served this flush (hot-swap observability) — or a DeferredScore
-        # when the batch was posted to a worker process
-        out = self.score_fn(feats, key_lists)
-        if isinstance(out, DeferredScore):
-            return PendingFlush(self, batch, n, now, out, t0)
-        service = time.perf_counter() - t0
-        probs, staleness = out[0], out[1]
-        model_version = int(out[2]) if len(out) > 2 else 0
-        return self._results(batch, n, now, probs, staleness, model_version,
-                             service)
+            crashpoint.fire("flush.before_score")
+            t0 = time.perf_counter()
+            # scorers may return (probs, staleness) or, when version-aware,
+            # (probs, staleness, model_version) — the version whose jit cache
+            # served this flush (hot-swap observability) — or a DeferredScore
+            # when the batch was posted to a worker process
+            out = self.score_fn(feats, key_lists)
+            if isinstance(out, DeferredScore):
+                return PendingFlush(self, batch, n, now, out, t0, seq, kind)
+            service = time.perf_counter() - t0
+            probs, staleness = out[0], out[1]
+            model_version = int(out[2]) if len(out) > 2 else 0
+            return self._results(batch, n, now, probs, staleness,
+                                 model_version, service)
 
     def _results(self, batch, n, now, probs, staleness, model_version,
                  service) -> list[ScoredResult]:
         """Post-score half of a flush — shared by the synchronous path and
         :meth:`PendingFlush.resolve` so accounting and result construction
         cannot drift between backends."""
-        crashpoint.fire("flush.after_score")
-        self.stats["flushes"] += 1
-        return [
-            ScoredResult(
-                request=r,
-                score=float(probs[i]),
-                staleness=int(staleness[i]),
-                queued_s=max(0.0, now - r.arrival),
-                service_s=service,
-                batch_size=n,
-                model_version=model_version,
-            )
-            for i, r in enumerate(batch)
-        ]
+        with spans.span("batch.results"):
+            crashpoint.fire("flush.after_score")
+            self.stats["flushes"] += 1
+            return [
+                ScoredResult(
+                    request=r,
+                    score=float(probs[i]),
+                    staleness=int(staleness[i]),
+                    queued_s=max(0.0, now - r.arrival),
+                    service_s=service,
+                    batch_size=n,
+                    model_version=model_version,
+                )
+                for i, r in enumerate(batch)
+            ]

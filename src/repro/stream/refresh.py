@@ -60,7 +60,7 @@ from repro.core.graph import pad_graph
 from repro.core.lnn import LNNConfig, lnn_stage1
 from repro.serve.kvstore import KVStore, pack_key
 from repro.stream.ingest import StreamIngester
-from repro.utils import crashpoint
+from repro.utils import crashpoint, spans
 
 
 def _pow2_at_least(n: int, floor: int = 64) -> int:
@@ -197,16 +197,18 @@ class RefreshDriver:
         Returns ``(pending, work, n_communities)`` where ``work`` is the
         full accumulated :class:`DDSGraph` (whole-graph mode) or a list of
         ``(subgraph, pairs)`` community bins (community-local mode)."""
-        if not self.community_local:
-            pending = self.ingester.take_refreshable(up_to_snapshot)
-            return pending, (self.ingester.materialize() if pending else None), 0
-        groups = self.ingester.take_refreshable_by_community(up_to_snapshot)
-        if not groups:
-            return [], None, 0
-        pending = sorted(p for _, pairs in groups for p in pairs)
-        work = [(self.ingester.materialize_communities(cids), pairs)
-                for cids, pairs in self._pack_bins(groups)]
-        return pending, work, len(groups)
+        with spans.span("refresh.snapshot"):
+            if not self.community_local:
+                pending = self.ingester.take_refreshable(up_to_snapshot)
+                return pending, (self.ingester.materialize() if pending
+                                 else None), 0
+            groups = self.ingester.take_refreshable_by_community(up_to_snapshot)
+            if not groups:
+                return [], None, 0
+            pending = sorted(p for _, pairs in groups for p in pairs)
+            work = [(self.ingester.materialize_communities(cids), pairs)
+                    for cids, pairs in self._pack_bins(groups)]
+            return pending, work, len(groups)
 
     def _pack_bins(self, groups) -> list:
         """Greedily pack dirty communities (ascending id — deterministic)
@@ -233,11 +235,12 @@ class RefreshDriver:
         """Run stage 1 over the dirty communities (or the whole accumulated
         graph with ``community_local=False``); write embeddings for the
         dirty (entity, t) pairs with t <= up_to_snapshot, versioned."""
-        params, model_version = self._snapshot_model()
-        pending, work, n_comms = self._snapshot_graph(up_to_snapshot)
-        if not pending:
-            return {"entities_written": 0, "seconds": 0.0}
-        return self._run(pending, work, n_comms, params, model_version)
+        with spans.span("refresh"):
+            params, model_version = self._snapshot_model()
+            pending, work, n_comms = self._snapshot_graph(up_to_snapshot)
+            if not pending:
+                return {"entities_written": 0, "seconds": 0.0}
+            return self._run(pending, work, n_comms, params, model_version)
 
     def _shard_groups(self, pending) -> list[tuple[int, list]]:
         """Group dirty (entity, t) pairs by owning speed-layer shard, shard
@@ -255,9 +258,11 @@ class RefreshDriver:
         """One stage-1 forward per padded graph: via the executor (shard
         processes, off the serving GIL) when one is attached, else the
         inline jit — identical outputs either way."""
-        if self.stage1_executor is not None:
-            return self.stage1_executor(pgs, entity_hints, int(model_version))
-        return [np.asarray(self._stage1(params, pg)) for pg in pgs]
+        with spans.span("refresh.stage1"):
+            if self.stage1_executor is not None:
+                return self.stage1_executor(pgs, entity_hints,
+                                            int(model_version))
+            return [np.asarray(self._stage1(params, pg)) for pg in pgs]
 
     def _stage1_embeddings(self, params, model_version, pending,
                            work) -> tuple[dict, int, int]:
@@ -272,14 +277,16 @@ class RefreshDriver:
         emb: dict = {}
         if isinstance(work, list):          # community-local bins
             pgs, hints, total = [], [], 0
-            for sub, pairs in work:
-                budget = _pow2_at_least(sub.coo.num_nodes)
-                pgs.append(pad_graph(sub.coo, num_nodes=budget,
-                                     max_deg=self.max_deg))
-                # dispatch hint: the bin's first dirty entity — community-
-                # local bins land on the shard process owning their entities
-                hints.append(pairs[0][0] if pairs else 0)
-                total += budget
+            with spans.span("refresh.pad"):
+                for sub, pairs in work:
+                    budget = _pow2_at_least(sub.coo.num_nodes)
+                    pgs.append(pad_graph(sub.coo, num_nodes=budget,
+                                         max_deg=self.max_deg))
+                    # dispatch hint: the bin's first dirty entity — community-
+                    # local bins land on the shard process owning their
+                    # entities
+                    hints.append(pairs[0][0] if pairs else 0)
+                    total += budget
             hs = self._run_stage1(pgs, hints, params, model_version)
             for h, (sub, pairs) in zip(hs, work):
                 for ent, t in pairs:
@@ -289,7 +296,8 @@ class RefreshDriver:
             return emb, total, len(work)
         dds = work                           # whole-graph path
         budget = _pow2_at_least(dds.coo.num_nodes)
-        pg = pad_graph(dds.coo, num_nodes=budget, max_deg=self.max_deg)
+        with spans.span("refresh.pad"):
+            pg = pad_graph(dds.coo, num_nodes=budget, max_deg=self.max_deg)
         hint = pending[0][0] if pending else 0
         h = self._run_stage1([pg], [hint], params, model_version)[0]
         for ent, t in pending:
@@ -309,19 +317,20 @@ class RefreshDriver:
         with self._lock:
             self.version += 1
             written = 0
-            for shard, pairs in groups:
-                # one batched put per shard feed: a single store lock
-                # acquisition per group instead of one per embedding
-                resolved = [(pack_key(ent, t), emb[(ent, t)])
-                            for ent, t in pairs if (ent, t) in emb]
-                shard_written = self.store.put_batch(
-                    [k for k, _ in resolved],
-                    (v for _, v in resolved),
-                    version=self.version, model_version=model_version,
-                ) if resolved else 0
-                per = self.stats["per_shard_written"]
-                per[shard] = per.get(shard, 0) + shard_written
-                written += shard_written
+            with spans.span("refresh.put"):
+                for shard, pairs in groups:
+                    # one batched put per shard feed: a single store lock
+                    # acquisition per group instead of one per embedding
+                    resolved = [(pack_key(ent, t), emb[(ent, t)])
+                                for ent, t in pairs if (ent, t) in emb]
+                    shard_written = self.store.put_batch(
+                        [k for k, _ in resolved],
+                        (v for _, v in resolved),
+                        version=self.version, model_version=model_version,
+                    ) if resolved else 0
+                    per = self.stats["per_shard_written"]
+                    per[shard] = per.get(shard, 0) + shard_written
+                    written += shard_written
             # stats are read-modify-writes shared with concurrent sync
             # callers — they stay under the same lock as the puts
             dt = time.monotonic() - t0

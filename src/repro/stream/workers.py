@@ -47,6 +47,7 @@ from repro.stream.microbatch import (
     ScoreRequest,
     bucket_size,
 )
+from repro.utils import spans
 
 
 class ShardRouter:
@@ -197,22 +198,34 @@ class Stage2Scorer:
 
     def _score(self, params, version, stage2, hybrid, feats,
                entity_t_lists, emb, mask, stale):
+        """Stage 2 and the host tail.  Spans (``utils.spans``): ``s2.launch``
+        is the jitted call, which returns once the launch is enqueued
+        (dispatch and the arguments' transfer); ``s2.sync`` is reading its
+        result back (waiting for the kernel, then the device-to-host copy);
+        ``s2.tail`` is the host sigmoid or GBDT head and the staleness."""
         f = np.ascontiguousarray(feats, np.float32)
         st = self._slot_types(entity_t_lists) if self._typed else None
         if hybrid:
             # one jit dispatch for the fused embedding, booster on host —
             # numpy trees are element-deterministic, replay parity holds
-            x = np.asarray(stage2(params.lnn_params, emb, mask, f, st),
-                           np.float32)
-            probs = params.gbdt.predict_proba(x).astype(np.float32)
-            return probs, stale.max(axis=1), version
-        logits = np.asarray(stage2(params, emb, mask, f, st), np.float64)
+            with spans.span("s2.launch"):
+                out = stage2(params.lnn_params, emb, mask, f, st)
+            with spans.span("s2.sync"):
+                x = np.asarray(out, np.float32)
+            with spans.span("s2.tail"):
+                probs = params.gbdt.predict_proba(x).astype(np.float32)
+                return probs, stale.max(axis=1), version
+        with spans.span("s2.launch"):
+            out = stage2(params, emb, mask, f, st)
+        with spans.span("s2.sync"):
+            logits = np.asarray(out, np.float64)
         # host-side f64 sigmoid, NOT jax.nn.sigmoid: XLA CPU's vectorized
         # exp rounds differently per array length (bucket 2 vs 4 diverge by
         # 1 ulp), while numpy ufuncs are element-deterministic for any
         # shape — required for the bit-exact replay-parity guarantee
-        probs = (1.0 / (1.0 + np.exp(-logits))).astype(np.float32)
-        return probs, stale.max(axis=1), version
+        with spans.span("s2.tail"):
+            probs = (1.0 / (1.0 + np.exp(-logits))).astype(np.float32)
+            return probs, stale.max(axis=1), version
 
     def warmup(self, max_batch: int):
         """Compile every pow2 bucket shape this worker's batcher can emit."""
@@ -275,7 +288,7 @@ class SpeedLayerWorker:
         trigger, the end of the previous flush's service window, or the
         moment stolen work arrived — whichever is latest)."""
         stamp = max(trigger, self.busy_until, self.stamp_floor)
-        out = self.batcher.flush(stamp)
+        out = self.batcher.flush(stamp, kind=kind.removesuffix("_flushes"))
         if out:
             self.batcher.stats[kind] += 1
             if isinstance(out, PendingFlush):

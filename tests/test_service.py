@@ -442,7 +442,7 @@ def test_service_stats_json_roundtrip():
         shed=7, blocked=5, block_timeouts=3, queue_depth=4,
         queue_depth_peak=12, in_flight_peak=2, flushes=31, refreshes=6,
         entities_written=250, model_stale_reads=11, store_size=420,
-        rollbacks=1, last_good_version=0,
+        rollbacks=1, last_good_version=0, compiles=5,
         scores_by_version={0: 40, 3: 50},
         shadow={"version": 9, "fraction": 0.5, "threshold": 0.25,
                 "sampled": 45, "divergence_sum": 0.5, "divergence_max": 0.1,
@@ -471,6 +471,10 @@ def test_service_stats_json_roundtrip():
     # the live service produces the same lossless round-trip
     live = ServiceStats.from_dict(json.loads(json.dumps(sample.to_dict())))
     assert live.to_dict() == sample.to_dict()
+    # the gateway renders the compile counter from the same snapshot
+    from repro.gateway.server import service_metric_lines
+
+    assert "repro_service_compiles_total 5" in service_metric_lines(wire)
 
 
 # ------------------------------------------------- bounded block-mode stalls

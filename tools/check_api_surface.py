@@ -26,9 +26,10 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, os.path.join(str(ROOT), "src"))
 
 #: the reviewed serving surface: the typed API, the HTTP gateway over it,
-#: both shim packages, and the crash-consistency layer
+#: both shim packages, the crash-consistency layer, and the program spans
+#: an operator switches on to take a profile
 MODULES = ["repro.service", "repro.gateway", "repro.learn", "repro.serve",
-           "repro.stream", "repro.stream.checkpoint"]
+           "repro.stream", "repro.stream.checkpoint", "repro.utils.spans"]
 
 SNAPSHOT = ROOT / "tools" / "api_surface.json"
 
