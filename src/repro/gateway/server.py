@@ -137,6 +137,8 @@ _SERVICE_SCALARS = [
     ("queue_depth_peak", "repro_service_queue_depth_peak", "gauge"),
     ("in_flight_peak", "repro_service_in_flight_peak", "gauge"),
     ("flushes", "repro_service_flushes_total", "counter"),
+    ("deferred_flushes", "repro_service_deferred_flushes_total", "counter"),
+    ("blocking_resolves", "repro_service_blocking_resolves_total", "counter"),
     ("refreshes", "repro_service_refreshes_total", "counter"),
     ("entities_written", "repro_service_entities_written_total", "counter"),
     ("model_stale_reads", "repro_service_model_stale_reads_total", "counter"),
@@ -373,7 +375,8 @@ class FraudGateway:
                 results: list = []
                 for ev in events:
                     results.extend(svc.submit(ev))
-                pending = len(svc.engine.pool)
+                pool = svc.engine.pool
+                pending = len(pool) + pool.in_flight()
         else:
             items, single = self._body_items(body, "request", "requests")
             reqs = [request_from_json(d) for d in items]

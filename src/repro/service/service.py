@@ -506,9 +506,12 @@ class FraudService:
 
         Samples admitted responses at the configured fraction (deterministic
         error-accumulator sampling, not RNG — replays sample identically),
-        re-scores them in ONE padded stage-2 dispatch under the shadow
-        version against the live KV store, and folds |primary − shadow|
-        into the divergence counters.  Returns the number sampled.
+        re-scores them in padded stage-2 dispatches of at most
+        ``max_batch`` rows under the shadow version against the live KV
+        store, and folds |primary − shadow| into the divergence counters.
+        Returns the number sampled.  (One call may deliver more than
+        ``max_batch`` responses: flushes resolved on a later call arrive
+        together.)
 
         In streaming mode the shadow batch is padded to the same pow2
         buckets the speed layer uses, so an identical-weights shadow
@@ -531,7 +534,10 @@ class FraudService:
                     picked.append(r)
         if not picked:
             return 0
-        shadow_scores = self._shadow_score([r.request for r in picked], version)
+        cap = max(2, self.config.engine.max_batch)
+        shadow_scores = np.concatenate([
+            self._shadow_score([r.request for r in picked[i:i + cap]], version)
+            for i in range(0, len(picked), cap)])
         with self._shadow_lock:
             sh = self._shadow
             if sh is None or sh["version"] != version:
@@ -871,7 +877,8 @@ class FraudService:
         mid-effect and has no consistent snapshot) but does NOT flush the
         worker queues — queued requests are checkpointed as queued, so the
         restored run's flush compositions (and hence its bit-exact scores)
-        are unchanged."""
+        are unchanged.  Flushes still in flight are resolved into the
+        reorder buffer and checkpointed as held results."""
         from repro.stream import checkpoint as ckpt
 
         self._ensure(_SERVABLE, "checkpoint")
@@ -1071,14 +1078,17 @@ class FraudService:
         if self.mode == "streaming" and self._engine is not None:
             pool = self._engine.pool
             st.queue_depth = len(pool)
-            st.flushes = pool.stats["flushes"]
+            pool_stats = pool.stats
+            st.flushes = pool_stats["flushes"]
+            st.deferred_flushes = pool_stats["deferred_flushes"]
+            st.blocking_resolves = pool_stats["blocking_resolves"]
             st.refreshes = self._engine.refresher.stats["refreshes"]
             st.entities_written = self._engine.refresher.stats["entities_written"]
             # ONE worker_summary() call: the typed field and the legacy
             # extra entry alias the same tear-free snapshot
             workers = pool.worker_summary()
             st.workers = workers
-            st.extra = {"pool": dict(pool.stats), "workers": workers}
+            st.extra = {"pool": dict(pool_stats), "workers": workers}
             if self._autoscaler is not None:
                 st.extra["autoscaler"] = dict(self._autoscaler.stats)
         elif self._batch_layer is not None:

@@ -94,6 +94,8 @@ class ServiceStats:
     queue_depth_peak: int = 0               # high-water mark since build
     in_flight_peak: int = 0                 # busy-worker high-water mark
     flushes: int = 0
+    deferred_flushes: int = 0               # scores handed back on a later call
+    blocking_resolves: int = 0              # flush resolves that waited on the device
     refreshes: int = 0
     entities_written: int = 0
     model_stale_reads: int = 0              # KV hits stamped by an older model

@@ -443,6 +443,9 @@ def snapshot_state(service, applied_seq: int) -> tuple[dict, dict]:
         [it[4] for it in items], np.int64)
     arrays["kv_shard_off"] = np.asarray(shard_off, np.int64)
 
+    # an in-flight flush is neither queued nor held: settle it into the
+    # reorder buffer, which the snapshot captures
+    pool.settle()
     queued = [(w.wid, r) for w in pool.workers
               for r in list(w.batcher._queue)]
     held = [pool._reorder._held[s] for s in sorted(pool._reorder._held)]

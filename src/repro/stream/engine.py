@@ -198,7 +198,8 @@ class StreamingEngine:
         one jitted stage-2 call, the checkout-approval hot path.  Kept as
         the direct entry the benches and parity tests drive (the scorer's
         model-version stamp is dropped here; results carry it)."""
-        probs, staleness, _ = self.pool.workers[0].scorer(feats, entity_t_lists)
+        probs, staleness, _ = self.pool.workers[0].scorer(
+            feats, entity_t_lists).wait()
         return probs, staleness
 
     def warmup(self):

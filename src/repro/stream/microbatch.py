@@ -54,33 +54,41 @@ def bucket_size(n: int, max_batch: int) -> int:
 
 
 class DeferredScore:
-    """A score_fn result still in flight (process-backed scorers).
+    """A score_fn result still in flight.
 
-    An inline ``score_fn`` returns ``(probs, staleness[, model_version])``
-    synchronously; a process-backed scorer posts the padded batch to its
-    owner process and returns one of these instead.  ``wait()`` blocks
-    until the reply frame lands and returns the same tuple the inline call
-    would have.  :meth:`MicroBatcher.flush` turns a deferred result into a
-    :class:`PendingFlush`, which the pool resolves before any result is
-    released — delivery order and accounting stay inline-identical while
-    several posted flushes overlap in flight across worker processes.
+    An inline ``score_fn`` may return ``(probs, staleness[, model_version])``
+    synchronously; the stage-2 scorer (``stream.workers.Stage2Scorer``)
+    returns one of these once its launch is enqueued and the read-back
+    started, and a process-backed scorer once the padded batch is posted to
+    its owner process.  ``wait()`` blocks until the scores are on the host
+    and returns the same tuple the synchronous call would have; ``ready()``
+    says without blocking whether ``wait()`` would return at once (always
+    true where no ``ready`` check was given, as on the process backend).
+    :meth:`MicroBatcher.flush` turns a deferred result into a
+    :class:`PendingFlush`, which the pool resolves later — delivery order
+    and accounting stay those of the synchronous path.
     """
 
-    def __init__(self, wait):
+    def __init__(self, wait, ready=None):
         self._wait = wait
+        self._ready = ready
 
     def wait(self):
         return self._wait()
 
+    def ready(self) -> bool:
+        return self._ready is None or bool(self._ready())
+
 
 class PendingFlush:
-    """A flush whose scores are still crossing a process boundary.
+    """A flush whose scores are still on their way to the host.
 
     Carries everything :meth:`MicroBatcher.flush` had already decided —
     the popped batch, its real row count, the trigger stamp — so
     ``resolve()`` can finish result construction exactly as the inline
     path would have.  Truthiness mirrors a non-empty result list, so the
-    worker's per-kind flush accounting is unchanged.
+    worker's per-kind flush accounting is unchanged.  ``carried`` is set by
+    the pool when the flush is still in flight as a later call begins.
     """
 
     def __init__(self, batcher, batch, n, now, deferred, t0, seq=0,
@@ -91,6 +99,7 @@ class PendingFlush:
         self.now = now
         self.deferred = deferred
         self.worker = None          # stamped by the worker that flushed
+        self.carried = False
         self._t0 = t0
         self.seq = seq
         self.kind = kind
@@ -98,10 +107,14 @@ class PendingFlush:
     def __bool__(self) -> bool:
         return True
 
+    def ready(self) -> bool:
+        """True when :meth:`resolve` would not block."""
+        return self.deferred.ready()
+
     def resolve(self) -> list:
-        """Block on the reply and build the ScoredResults (parent side).
-        Its ``batch.flush`` span carries the same ``seq`` as the one that
-        posted the batch."""
+        """Wait for the scores and build the ScoredResults.  Its
+        ``batch.flush`` span carries the same ``seq`` as the one that
+        launched or posted the batch."""
         with spans.span("batch.flush", seq=self.seq, n=self.n,
                         trigger=self.kind):
             probs, staleness, model_version = self.deferred.wait()
@@ -229,8 +242,8 @@ class MicroBatcher:
         """Score everything queued as one padded fixed-shape batch.
 
         Returns the ``ScoredResult`` list, or a :class:`PendingFlush` when
-        the scorer answered with a :class:`DeferredScore` (process backend
-        — the pool resolves it before releasing results).
+        the scorer answered with a :class:`DeferredScore` (the stage-2
+        scorer and the process backend — the pool resolves it later).
 
         The pop is atomic and re-checks emptiness: a concurrent drain (work
         steal, another flush) between the trigger firing and this pop must
@@ -240,8 +253,9 @@ class MicroBatcher:
         span, with the flush's sequence number, real size and trigger
         ``kind`` (size, deadline or forced) as metadata; inside it
         ``batch.assemble``, the scorer's own spans, and ``batch.results``.
-        On the process backend the post and the resolve are two
-        ``batch.flush`` spans with the same ``seq``."""
+        A deferred flush is two ``batch.flush`` spans with the same
+        ``seq``: the launch (or post) here, and the resolve, which holds
+        the stage-2 read (``s2.sync``, ``s2.tail``) and ``batch.results``."""
         if now is None:
             now = self.clock()
         with self._lock:
@@ -269,7 +283,7 @@ class MicroBatcher:
             # scorers may return (probs, staleness) or, when version-aware,
             # (probs, staleness, model_version) — the version whose jit cache
             # served this flush (hot-swap observability) — or a DeferredScore
-            # when the batch was posted to a worker process
+            # while the scores are still on their way to the host
             out = self.score_fn(feats, key_lists)
             if isinstance(out, DeferredScore):
                 return PendingFlush(self, batch, n, now, out, t0, seq, kind)

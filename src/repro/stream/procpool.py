@@ -708,9 +708,10 @@ class ProcessWorkerPool(WorkerPool):
     real processes.  Scheduling (queues, triggers, stealing, reorder,
     virtual clock) is inherited unchanged; each worker's ``score_fn`` is
     replaced by one that posts a SCORE frame to its shard process and
-    returns a :class:`DeferredScore` — the pool's ``_collect`` resolves
-    all of a pump pass's in-flight flushes together, which is where the
-    multi-process parallelism comes from.
+    returns a :class:`DeferredScore` that reads as always ready, so the
+    pool resolves all of a pump pass's posted flushes together at the end
+    of the call that posted them, which is where the multi-process
+    parallelism comes from.
     """
 
     def __init__(self, params, cfg, store_cfg: dict, num_workers: int = 1,

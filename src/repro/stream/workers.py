@@ -41,13 +41,14 @@ from repro.core.lnn import LNNConfig, lnn_stage2_embed, lnn_stage2_online
 from repro.models.hybrid import HybridModel
 from repro.serve.kvstore import KVStore, entity_shard
 from repro.stream.microbatch import (
+    DeferredScore,
     MicroBatcher,
     PendingFlush,
     ScoredResult,
     ScoreRequest,
     bucket_size,
 )
-from repro.utils import spans
+from repro.utils import crashpoint, spans
 
 
 class ShardRouter:
@@ -110,11 +111,58 @@ class ShardRouter:
         return self._epoch
 
 
+def _sigmoid(logits: np.ndarray) -> np.ndarray:
+    # host-side f64 sigmoid, NOT jax.nn.sigmoid: XLA CPU's vectorized
+    # exp rounds differently per array length (bucket 2 vs 4 diverge by
+    # 1 ulp), while numpy ufuncs are element-deterministic for any
+    # shape — required for the bit-exact replay-parity guarantee
+    return 1.0 / (1.0 + np.exp(-logits))
+
+
+class ScoresInFlight:
+    """A flush's probabilities while stage 2's output is on its way to the
+    host.  The launch has started the device-to-host copy
+    (``copy_to_host_async``).  :meth:`ready` asks without blocking whether
+    the output is ready; :meth:`result` reads it (span ``s2.sync``) and
+    runs the host head on it (``s2.tail``: the f64 sigmoid or the GBDT),
+    once.  numpy reads the object as that result (``np.asarray``,
+    ``copy()``)."""
+
+    def __init__(self, out: jax.Array, dtype, head):
+        self._out = out
+        self._dtype = dtype
+        self._head = head
+        self._probs: np.ndarray | None = None
+
+    def ready(self) -> bool:
+        return self._probs is not None or self._out.is_ready()
+
+    def result(self) -> np.ndarray:
+        if self._probs is None:
+            with spans.span("s2.sync"):
+                x = np.asarray(self._out, self._dtype)
+            with spans.span("s2.tail"):
+                self._probs = self._head(x).astype(np.float32)
+        return self._probs
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        return np.array(self.result(), dtype=dtype, copy=copy)
+
+    def copy(self) -> np.ndarray:
+        return self.result().copy()
+
+
 class Stage2Scorer:
     """The speed-layer scoring callable for one worker: one versioned KV
     multi-get (snapshot fallback + staleness) and ONE jitted stage-2
     dispatch (the fused Pallas launch when ``cfg.use_pallas``).  Each
     worker owns its own instance, hence its own jit caches.
+
+    A call does not wait for the device: it launches stage 2, starts the
+    read-back, and returns a :class:`DeferredScore` whose ``wait()`` reads
+    the output and runs the host head (:class:`ScoresInFlight`), so the
+    host can prepare the next flush while this one's result travels back.
+    The pool resolves it on a later call (:class:`WorkerPool`).
 
     The jit cache is **version-aware**: :meth:`set_model` registers a new
     parameter version under its own ``jax.jit`` wrapper, so swapping back
@@ -173,6 +221,9 @@ class Stage2Scorer:
         return st
 
     def __call__(self, feats: np.ndarray, entity_t_lists: list):
+        """One flush: the KV multi-get, then stage 2 launched and its
+        read-back started.  Returns a :class:`DeferredScore` whose
+        ``wait()`` gives ``(probs, staleness, model_version)``."""
         # capture the active model ONCE per flush: an in-flight micro-batch
         # finishes on the version it started with even if set_model lands
         # mid-flush (async refresh thread / live hot-swap)
@@ -181,8 +232,13 @@ class Stage2Scorer:
         emb, mask, stale = self.store.lookup_batch_versioned(
             entity_t_lists, self.k_max, expected_model_version=version
         )
-        return self._score(params, version, stage2, hybrid,
-                           feats, entity_t_lists, emb, mask, stale)
+        probs, stale_max, version = self._score(
+            params, version, stage2, hybrid, feats, entity_t_lists, emb,
+            mask, stale)
+        # a _score that is wrapped or replaced may hand back finished
+        # probabilities, which are ready at once
+        return DeferredScore(lambda: (np.asarray(probs), stale_max, version),
+                             ready=getattr(probs, "ready", None))
 
     def score_slots(self, feats: np.ndarray, entity_t_lists: list,
                     emb: np.ndarray, mask: np.ndarray, stale: np.ndarray):
@@ -190,42 +246,37 @@ class Stage2Scorer:
         process path: the parent pre-reads cross-shard slots from their
         owners and the owner process fills its local slots, then calls
         this with the merged ``(emb, mask, stale)``.  Numerically identical
-        to ``__call__`` by construction (same ``_score`` tail)."""
+        to ``__call__`` by construction (same ``_score``), and finished
+        before it returns."""
         params, version, stage2, hybrid = (
             self.params, self.model_version, self._stage2, self._hybrid)
-        return self._score(params, version, stage2, hybrid,
-                           feats, entity_t_lists, emb, mask, stale)
+        probs, stale_max, version = self._score(
+            params, version, stage2, hybrid, feats, entity_t_lists, emb,
+            mask, stale)
+        return np.asarray(probs), stale_max, version
 
     def _score(self, params, version, stage2, hybrid, feats,
                entity_t_lists, emb, mask, stale):
-        """Stage 2 and the host tail.  Spans (``utils.spans``): ``s2.launch``
-        is the jitted call, which returns once the launch is enqueued
-        (dispatch and the arguments' transfer); ``s2.sync`` is reading its
-        result back (waiting for the kernel, then the device-to-host copy);
-        ``s2.tail`` is the host sigmoid or GBDT head and the staleness."""
+        """Launch stage 2 and start its read-back, without waiting for it.
+        Returns ``(probs, staleness, model_version)`` with ``probs`` a
+        :class:`ScoresInFlight`.  Span ``s2.launch`` (``utils.spans``) is
+        the jitted call, which returns once the launch is enqueued
+        (dispatch and the arguments' transfer), and the start of the
+        device-to-host copy; ``s2.sync`` and ``s2.tail`` run where the
+        probabilities are read."""
         f = np.ascontiguousarray(feats, np.float32)
         st = self._slot_types(entity_t_lists) if self._typed else None
         if hybrid:
             # one jit dispatch for the fused embedding, booster on host —
             # numpy trees are element-deterministic, replay parity holds
-            with spans.span("s2.launch"):
-                out = stage2(params.lnn_params, emb, mask, f, st)
-            with spans.span("s2.sync"):
-                x = np.asarray(out, np.float32)
-            with spans.span("s2.tail"):
-                probs = params.gbdt.predict_proba(x).astype(np.float32)
-                return probs, stale.max(axis=1), version
+            lnn, dtype, head = (params.lnn_params, np.float32,
+                                params.gbdt.predict_proba)
+        else:
+            lnn, dtype, head = params, np.float64, _sigmoid
         with spans.span("s2.launch"):
-            out = stage2(params, emb, mask, f, st)
-        with spans.span("s2.sync"):
-            logits = np.asarray(out, np.float64)
-        # host-side f64 sigmoid, NOT jax.nn.sigmoid: XLA CPU's vectorized
-        # exp rounds differently per array length (bucket 2 vs 4 diverge by
-        # 1 ulp), while numpy ufuncs are element-deterministic for any
-        # shape — required for the bit-exact replay-parity guarantee
-        with spans.span("s2.tail"):
-            probs = (1.0 / (1.0 + np.exp(-logits))).astype(np.float32)
-            return probs, stale.max(axis=1), version
+            out = stage2(lnn, emb, mask, f, st)
+            out.copy_to_host_async()
+        return ScoresInFlight(out, dtype, head), stale.max(axis=1), version
 
     def warmup(self, max_batch: int):
         """Compile every pow2 bucket shape this worker's batcher can emit."""
@@ -233,7 +284,7 @@ class Stage2Scorer:
                           for n in range(1, max_batch + 1)})
         for b in buckets:
             self(np.zeros((b, self.cfg.feat_dim), np.float32),
-                 [[] for _ in range(b)])
+                 [[] for _ in range(b)]).wait()
 
 
 class SpeedLayerWorker:
@@ -262,6 +313,8 @@ class SpeedLayerWorker:
         # the steal time, so its recorded waits must not be backdated to
         # the victim's original (long-missed) triggers
         self.stamp_floor = 0.0
+        # the flush launched last whose scores the pool has not taken yet
+        self.inflight: PendingFlush | None = None
         self.stats = {"stolen_in": 0, "stolen_out": 0,
                       "max_queue_depth": 0, "depth_sum": 0,
                       "depth_samples": 0, "restarts": 0}
@@ -292,8 +345,8 @@ class SpeedLayerWorker:
         if out:
             self.batcher.stats[kind] += 1
             if isinstance(out, PendingFlush):
-                # process backend: the batch is in flight to this worker's
-                # shard process; the pool resolves it before any release
+                # the scores are still on their way (the device's read-back
+                # or this worker's shard process): the pool resolves it
                 out.worker = self.wid
                 out = [out]
             else:
@@ -374,6 +427,20 @@ class WorkerPool:
 
     With ``num_workers=1`` the pool degenerates to exactly the single
     MicroBatcher engine: same triggers, same stamps, same scores.
+
+    Flushes in flight: a flush whose scorer answers with a
+    :class:`~repro.stream.microbatch.DeferredScore` stays in flight on its
+    worker, one per worker.  At the start and the end of every ``poll`` and
+    ``submit`` the pool resolves each in-flight flush that is ``ready()``,
+    without waiting; a worker's next launch resolves its older flush after
+    that launch, waiting if it must.  So ``submit`` returns the responses
+    that are complete, which may include earlier orders' and not yet its
+    own.  Every barrier resolves everything, waiting: ``flush`` (drain),
+    ``reshard``, ``drain_to_depth`` and :meth:`settle` (checkpoint
+    capture).  The process backend's deferred results are always ready, so
+    it resolves within the pass that posted them.  ``pool_stats`` counts
+    ``deferred_flushes`` (handed back on a later call than the one that
+    launched them) and ``blocking_resolves`` (resolves that had to wait).
     """
 
     def __init__(self, params, cfg: LNNConfig, store: KVStore,
@@ -397,7 +464,8 @@ class WorkerPool:
         ]
         self._reorder = _ReorderBuffer()
         self._seq = 0
-        self.pool_stats = {"steals": 0, "stolen_requests": 0, "routed": 0}
+        self.pool_stats = {"steals": 0, "stolen_requests": 0, "routed": 0,
+                           "deferred_flushes": 0, "blocking_resolves": 0}
 
     @property
     def num_workers(self) -> int:
@@ -406,29 +474,68 @@ class WorkerPool:
     def __len__(self) -> int:
         return sum(len(w) for w in self.workers)
 
+    def in_flight(self) -> int:
+        """Orders whose flush is launched and not yet resolved."""
+        return sum(w.inflight.n for w in self.workers
+                   if w.inflight is not None)
+
     # ------------------------------------------------------------------ pump
-    def _collect(self, results: list) -> list[ScoredResult]:
-        """Resolve any in-flight process flushes before results enter the
-        reorder buffer.  Inline flushes are already ScoredResults, so this
-        is the identity for the in-process backend; the process backend's
-        parallelism comes from several posted flushes resolving here
-        together after one pump pass — delivery order, checkpoint state,
-        and accounting stay inline-identical."""
-        if not any(isinstance(r, PendingFlush) for r in results):
-            return results
-        out: list[ScoredResult] = []
+    def _resolve(self, w: SpeedLayerWorker) -> None:
+        """Hand worker ``w``'s in-flight flush to the reorder buffer,
+        waiting for its scores if they are not there yet."""
+        pf, w.inflight = w.inflight, None
+        if not pf.ready():
+            self.pool_stats["blocking_resolves"] += 1
+        if pf.carried:
+            self.pool_stats["deferred_flushes"] += 1
+        self._reorder.add(pf.resolve())
+
+    def _settle_ready(self, begin: bool = False) -> None:
+        """Resolve every in-flight flush that is ready, without waiting.
+        ``begin``: a new call starts, so what is still in flight is carried
+        over to it."""
+        for w in self.workers:
+            if w.inflight is not None:
+                w.inflight.carried |= begin
+                if w.inflight.ready():
+                    self._resolve(w)
+
+    def settle(self) -> None:
+        """Barrier: resolve every in-flight flush into the reorder buffer,
+        waiting for the device.  The results are released by the next call;
+        checkpoint capture calls this, so nothing is in flight when it reads
+        the queues and the reorder buffer."""
+        for w in self.workers:
+            if w.inflight is not None:
+                self._resolve(w)
+
+    def _collect(self, results: list) -> None:
+        """Take one pump pass's output: finished results go to the reorder
+        buffer; a launched flush stays in flight on its worker, and the
+        worker's older in-flight flush is resolved now, after the new
+        launch, so the two overlap."""
+        done: list[ScoredResult] = []
         for r in results:
-            out.extend(r.resolve() if isinstance(r, PendingFlush) else [r])
-        return out
+            if isinstance(r, PendingFlush):
+                crashpoint.fire("flush.in_flight")
+                w = self.workers[r.worker]
+                if w.inflight is not None:
+                    self._resolve(w)
+                w.inflight = r
+            else:
+                done.append(r)
+        self._reorder.add(done)
 
     def poll(self, now: float) -> list[ScoredResult]:
         """Advance the virtual clock: fire every due trigger, then let idle
         workers steal from backed-up shards."""
+        self._settle_ready(begin=True)
         results: list[ScoredResult] = []
         for w in self.workers:
             results.extend(w.pump(now))
         results.extend(self._steal_pass(now))
-        self._reorder.add(self._collect(results))
+        self._collect(results)
+        self._settle_ready()
         return self._reorder.release()
 
     def submit(self, request: ScoreRequest, now: float) -> list[ScoredResult]:
@@ -443,6 +550,7 @@ class WorkerPool:
                 f"has {len(self.workers)} — the router was resharded without "
                 "the pool; use WorkerPool.reshard(n)"
             )
+        self._settle_ready(begin=True)
         request.seq = self._seq
         self._seq += 1
         w = self.workers[self.router.route(request.entity_keys)]
@@ -451,7 +559,8 @@ class WorkerPool:
         results = w.pump(now)
         for worker in self.workers:
             worker.sample_depth()
-        self._reorder.add(self._collect(results))
+        self._collect(results)
+        self._settle_ready()
         return self._reorder.release()
 
     def _steal_pass(self, now: float) -> list[ScoredResult]:
@@ -535,8 +644,7 @@ class WorkerPool:
         victim = max(self.workers, key=lambda w: (len(w), -w.wid))
         if len(victim) == 0:
             return []
-        results = victim._flush_at(now, "forced_flushes")
-        self._reorder.add(self._collect(results))
+        self._collect(victim._flush_at(now, "forced_flushes"))
         return self._reorder.release()
 
     def drain_to_depth(self, max_depth: int, now: float,
@@ -556,24 +664,33 @@ class WorkerPool:
         """
         results: list[ScoredResult] = []
         deadline = None if budget_s is None else clock() + budget_s
+        admitted = True
         while len(self) >= max_depth:
             if deadline is not None and clock() >= deadline:
-                return results, False
+                admitted = False
+                break
             before = len(self)
             results.extend(self.force_flush_deepest(now))
             if len(self) >= before:
                 # nothing freed (every queue empty, or the flush raced away):
                 # legacy mode admits over-cap; a bounded stall sheds instead
-                return results, deadline is None
-        return results, True
+                admitted = deadline is None
+                break
+        # a stall is a barrier: the producer gets every score it waited for
+        self.settle()
+        results.extend(self._reorder.release())
+        return results, admitted
 
     # ----------------------------------------------------------------- drain
     def flush(self, now: float | None = None) -> list[ScoredResult]:
-        """Drain every worker's queue (stream end) and the reorder buffer."""
+        """Drain every worker's queue (stream end), every in-flight flush
+        and the reorder buffer."""
+        self._settle_ready(begin=True)
         results: list[ScoredResult] = []
         for w in self.workers:
             results.extend(w.drain(now))
-        self._reorder.add(self._collect(results))
+        self._collect(results)
+        self.settle()
         out = self._reorder.release()
         assert len(self._reorder) == 0, "reorder buffer retained results"
         return out

@@ -23,15 +23,17 @@ from __future__ import annotations
 
 #: every registered boundary, in rough hot-path order.  ``.before``/
 #: ``.after`` pairs model dying just before vs just after the operation's
-#: side effects; ``checkpoint.mid`` fires after the state payload is on
-#: disk but before the manifest rename that commits it (a torn checkpoint
-#: must be invisible to recovery).
+#: side effects; ``flush.in_flight`` fires once a flush is launched and
+#: before its scores are read back; ``checkpoint.mid`` fires after the
+#: state payload is on disk but before the manifest rename that commits it
+#: (a torn checkpoint must be invisible to recovery).
 CRASH_POINTS = (
     "wal.append.before",
     "wal.append.after",
     "ingest.before",
     "ingest.after",
     "flush.before_score",
+    "flush.in_flight",
     "flush.after_score",
     "refresh.before_stage1",
     "refresh.before_puts",

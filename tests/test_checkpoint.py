@@ -21,6 +21,7 @@ from repro.stream.checkpoint import (CheckpointError, WriteAheadLog,
                                      latest_checkpoint, list_checkpoints,
                                      read_checkpoint, wal_path)
 from repro.stream.events import CheckoutEvent
+from repro.stream.microbatch import DeferredScore
 from repro.utils import crashpoint
 from repro.utils.crashpoint import SimulatedCrash
 
@@ -329,6 +330,60 @@ def test_checkpoint_restore_roundtrip_backends(tiny_world, tmp_path, backend):
             f"{backend}: KV bytes diverged across checkpoint/restore"
     finally:
         svc2.close()
+
+
+def test_a_flush_in_flight_is_checkpointed_and_replayed(tiny_world,
+                                                        tmp_path):
+    """A checkpoint taken while a flush is in flight holds its results; a
+    crash with a flush in flight (launched, scores not yet read back) loses
+    nothing: restore + WAL replay reproduce every score of an
+    uninterrupted run and answer every order."""
+    from faultinject import merge_responses
+
+    events, cfg, params = tiny_world
+    oracle = _build(cfg, params)
+    base = [r for ev in events for r in oracle.submit(ev)] + oracle.drain()
+    base_scores = merge_responses({}, base)
+    assert len(base_scores) == len(events)
+
+    root = str(tmp_path)
+    svc = _build(cfg, params).enable_wal(root)
+    pool = svc.engine.pool
+    for w in pool.workers:
+        # never ready: a flush stays in flight until the next launch
+        score = w.batcher.score_fn
+        w.batcher.score_fn = lambda f, k, s=score: DeferredScore(
+            s(f, k).wait, ready=lambda: False)
+    delivered = []
+    for ev in events[:10]:
+        delivered.extend(svc.submit(ev))
+    assert pool.workers[0].inflight is not None
+    held = {r.seq for r in pool.workers[0].inflight.batch}
+    path = svc.checkpoint()
+    assert pool.workers[0].inflight is None
+    manifest, arrays = read_checkpoint(path)
+    saved = arrays["rq_seq"][arrays["rq_location"] == -1]
+    assert held <= set(saved.tolist())
+    crashpoint.arm("flush.in_flight")
+    try:
+        with pytest.raises(SimulatedCrash):
+            for ev in events[10:]:
+                delivered.extend(svc.submit(ev))
+    finally:
+        crashpoint.disarm()
+    assert len(merge_responses({}, delivered)) < len(events)
+    svc._wal.close()
+
+    svc2 = FraudService.restore(root)
+    merged = merge_responses({}, delivered)
+    merge_responses(merged, svc2.last_recovery["responses"])
+    rest = []
+    for ev in events[svc2.engine.ingester.num_events:]:
+        rest.extend(svc2.submit(ev))
+    rest.extend(svc2.drain())
+    merge_responses(merged, rest)
+    assert merged == base_scores
+    svc2.close()
 
 
 def test_restore_rejects_future_format(tiny_world, tmp_path):

@@ -46,6 +46,7 @@ _HITS = {
     "ingest.before": 35,       # fires per submitted event (60 total)
     "ingest.after": 35,
     "flush.before_score": 8,   # fires per micro-batch flush (~15 total)
+    "flush.in_flight": 8,
     "flush.after_score": 8,
     "refresh.before_stage1": 6,   # fires per non-empty refresh window
     "refresh.before_puts": 6,

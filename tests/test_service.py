@@ -208,6 +208,25 @@ def test_streaming_mode_bit_identical_to_engine(service_world, num_workers):
     assert st.shed == 0 and st.blocked == 0
 
 
+def test_shadow_rescores_a_delivery_larger_than_max_batch(service_world):
+    """A call may deliver more than ``max_batch`` responses (flushes
+    resolved on a later call arrive together, and a drain returns every
+    one owed): the shadow re-scores them in bucket-sized dispatches, and an
+    identical-weights shadow still diverges by exactly 0.0."""
+    events, cfg, params, sc = service_world
+    svc = FraudService(sc.replace(engine={"max_batch": 4}),
+                       params=params).build()
+    svc.register_model(params, version=5)
+    svc.enable_shadow(5, fraction=1.0, threshold=1e-12)
+    out = [r for ev in events[:40] for r in svc.submit(ev)] + svc.drain()
+    assert len(out) == 40
+    assert svc.shadow_observe(out) == 40
+    sh = svc.shadow_stats()
+    assert sh["sampled"] == 40
+    assert sh["divergence_max"] == 0.0 and sh["alerts"] == 0
+    svc.close()
+
+
 def test_replay_report_summary_single_latency_pass(service_world):
     events, cfg, params, sc = service_world
     svc = FraudService(sc, params=params).build()
@@ -440,7 +459,8 @@ def test_service_stats_json_roundtrip():
         mode="streaming", state="serving", model_version=3,
         model_versions=(0, 3, 9), model_swaps=2, requests=100, scored=90,
         shed=7, blocked=5, block_timeouts=3, queue_depth=4,
-        queue_depth_peak=12, in_flight_peak=2, flushes=31, refreshes=6,
+        queue_depth_peak=12, in_flight_peak=2, flushes=31,
+        deferred_flushes=27, blocking_resolves=2, refreshes=6,
         entities_written=250, model_stale_reads=11, store_size=420,
         rollbacks=1, last_good_version=0, compiles=5,
         scores_by_version={0: 40, 3: 50},
@@ -474,7 +494,10 @@ def test_service_stats_json_roundtrip():
     # the gateway renders the compile counter from the same snapshot
     from repro.gateway.server import service_metric_lines
 
-    assert "repro_service_compiles_total 5" in service_metric_lines(wire)
+    lines = service_metric_lines(wire)
+    assert "repro_service_compiles_total 5" in lines
+    assert "repro_service_deferred_flushes_total 27" in lines
+    assert "repro_service_blocking_resolves_total 2" in lines
 
 
 # ------------------------------------------------- bounded block-mode stalls

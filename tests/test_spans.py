@@ -115,9 +115,10 @@ def test_a_traced_streaming_run_emits_every_span_on_its_path(
         by.setdefault(name, []).append((s, e))
     assert len(by["service.submit"]) == len(events)
     assert len(by["ingest.order"]) == len(events)
-    # one batch.flush per flush the service counted, and every stage-2
-    # span of the window nested inside one of them
-    assert len(by["batch.flush"]) == flushes
+    # two batch.flush spans with one seq per flush the service counted:
+    # the launch, and the resolve on a later call or at the drain; every
+    # stage-2 span of the window nested inside one of them
+    assert len(by["batch.flush"]) == 2 * flushes
     for name in ("batch.assemble", "kv.lookup", "s2.launch", "s2.sync",
                  "s2.tail", "batch.results"):
         assert len(by[name]) == flushes, name
@@ -134,7 +135,7 @@ def test_a_traced_streaming_run_emits_every_span_on_its_path(
         sorted(ev.order_id for ev in events)
     flush_meta = meta["batch.flush"]
     assert len({m["seq"] for m in flush_meta}) == flushes
-    assert sum(m["n"] for m in flush_meta) == len(events)
+    assert sum(m["n"] for m in flush_meta) == 2 * len(events)
     assert {m["trigger"] for m in flush_meta} <= {"size", "deadline",
                                                   "forced"}
 

@@ -11,6 +11,7 @@ from repro.core.graph import pad_graph
 from repro.data import SynthConfig, generate_event_stream
 from repro.stream import (
     CheckoutEvent,
+    DeferredScore,
     EngineConfig,
     MicroBatcher,
     ScoreRequest,
@@ -389,6 +390,42 @@ def test_replay_parity_under_randomized_flush_interleavings(stream_world):
         s = StreamingEngine(params, cfg, ecfg).replay(evs).scores_by_order()
         assert set(s) == set(s_ref)
         assert all(s[o] == s_ref[o] for o in s_ref), (trial, ecfg)
+
+
+def test_engine_flush_hands_back_every_flush_in_flight(stream_world):
+    """Flushes kept in flight across calls score bit for bit as flushes
+    finished inside the call that launched them, arrive in submission
+    order, and the engine's flush leaves none in flight."""
+    events, g, cfg, params = stream_world
+    evs = events[:120]
+    scores, stats = {}, {}
+    for mode in ("sync", "held"):
+        eng = StreamingEngine(params, cfg,
+                              EngineConfig(max_batch=8, num_workers=2))
+        for w in eng.pool.workers:
+            score = w.batcher.score_fn
+            if mode == "sync":
+                # a finished tuple: the batcher's synchronous path
+                w.batcher.score_fn = lambda f, k, s=score: s(f, k).wait()
+            else:
+                # never ready: each flush waits for its worker's next
+                # launch or for the barrier
+                w.batcher.score_fn = lambda f, k, s=score: DeferredScore(
+                    s(f, k).wait, ready=lambda: False)
+        results = []
+        for ev in evs:
+            results.extend(eng.submit(ev))
+        assert any(w.inflight is not None for w in eng.pool.workers) \
+            == (mode == "held")
+        results.extend(eng.flush())
+        assert all(w.inflight is None for w in eng.pool.workers)
+        assert [r.request.seq for r in results] == list(range(len(evs)))
+        scores[mode] = {r.request.tag.order_id: r.score for r in results}
+        stats[mode] = eng.pool.stats
+    assert scores["held"] == scores["sync"]
+    assert stats["sync"]["deferred_flushes"] == 0
+    assert stats["held"]["deferred_flushes"] > 0
+    assert stats["held"]["blocking_resolves"] == stats["held"]["flushes"]
 
 
 def test_multiworker_results_arrive_in_submission_order(stream_world):

@@ -1,18 +1,27 @@
 """Multi-worker sharded speed layer: router key-affinity, rendezvous
 minimal movement, explicit-reshard-only semantics, work stealing, the
-reorder collector, and per-worker flush independence."""
+reorder collector, per-worker flush independence, and flushes kept in
+flight across calls."""
+import jax
 import numpy as np
 import pytest
 
+from repro.core import LNNConfig, lnn_init
 from repro.dist.sharding import rendezvous_shard, splitmix64, stable_shard
 from repro.serve.kvstore import KVStore, entity_shard, pack_key
 from repro.stream import (
+    DeferredScore,
     MicroBatcher,
     ScoreRequest,
     ShardRouter,
     WorkerPool,
 )
-from repro.stream.workers import SpeedLayerWorker, _ReorderBuffer
+from repro.stream.workers import (
+    ScoresInFlight,
+    SpeedLayerWorker,
+    Stage2Scorer,
+    _ReorderBuffer,
+)
 
 
 # ------------------------------------------------------------------ hashing
@@ -247,3 +256,160 @@ def test_take_steals_oldest_requests_atomically():
     assert mb.oldest_arrival == pytest.approx(0.2)
     assert mb.take(0) == []
     assert len(mb.take(99)) == 3 and len(mb) == 0
+
+
+# --------------------------------------------------- flushes kept in flight
+class _Flight:
+    """Scorer double: every flush answers with a DeferredScore whose
+    ``ready()`` the test controls (``landed``), and whose score is each
+    row's first feature (the request's seq, so the pairing is checked).
+    ``ready=None`` makes it answer as the process backend does."""
+
+    def __init__(self, controlled: bool = True):
+        self.controlled = controlled
+        self.landed = False
+        self.waits = 0
+
+    def __call__(self, feats, key_lists):
+        probs = feats[:, 0].copy()
+        stale = np.zeros(len(feats), np.int32)
+
+        def wait():
+            self.waits += 1
+            return probs, stale, 0
+
+        ready = (lambda: self.landed) if self.controlled else None
+        return DeferredScore(wait, ready=ready)
+
+
+def _flight_pool(num_workers=1, max_batch=2, controlled=True):
+    cfg = LNNConfig(num_gnn_layers=2, hidden_dim=4, feat_dim=4, mlp_dims=(4,))
+    store = KVStore(dim=4, num_shards=num_workers,
+                    shard_by_entity=num_workers > 1)
+    pool = WorkerPool(lnn_init(jax.random.PRNGKey(0), cfg), cfg, store,
+                      num_workers=num_workers, max_batch=max_batch,
+                      max_wait_s=10.0)
+    flight = _Flight(controlled)
+    for w in pool.workers:
+        w.batcher.score_fn = flight
+    return pool, flight
+
+
+def _order(i, entity=0):
+    f = np.zeros(4, np.float32)
+    f[0] = i
+    return ScoreRequest(features=f, entity_keys=[(entity, 0)],
+                        arrival=0.001 * i)
+
+
+def _in_flight(pool):
+    return [w.wid for w in pool.workers if w.inflight is not None]
+
+
+def test_a_flush_comes_back_on_a_later_call_once_ready():
+    pool, flight = _flight_pool()
+    assert pool.submit(_order(0), 0.0) == []
+    # the size flush launches inside this call and stays in flight
+    assert pool.submit(_order(1), 0.001) == []
+    assert _in_flight(pool) == [0] and pool.in_flight() == 2
+    assert pool.poll(0.002) == []              # not ready: nothing blocks
+    assert pool.submit(_order(2), 0.002) == []
+    assert flight.waits == 0
+    flight.landed = True
+    out = pool.poll(0.003)
+    assert [r.request.seq for r in out] == [0, 1]
+    assert [r.score for r in out] == [0.0, 1.0]
+    assert _in_flight(pool) == [] and pool.in_flight() == 0
+    assert pool.pool_stats["deferred_flushes"] == 1
+    assert pool.pool_stats["blocking_resolves"] == 0
+    assert pool.stats["flushes"] == 1
+
+
+def test_a_second_launch_on_the_same_worker_resolves_the_older_flush():
+    pool, flight = _flight_pool()
+    for i in range(3):
+        assert pool.submit(_order(i), 0.001 * i) == []
+    # the second launch resolves the first flush after it, waiting for it
+    out = pool.submit(_order(3), 0.003)
+    assert [r.request.seq for r in out] == [0, 1]
+    assert flight.waits == 1 and _in_flight(pool) == [0]
+    assert pool.pool_stats["blocking_resolves"] == 1
+    assert pool.pool_stats["deferred_flushes"] == 1
+    # one flush in flight per worker: with two workers, each keeps its own
+    pool, flight = _flight_pool(num_workers=2)
+    ents = [e for e in range(64) if pool.router.worker_of(e) == 0][:1] + \
+        [e for e in range(64) if pool.router.worker_of(e) == 1][:1]
+    seq = 0
+    for ent in ents:
+        for _ in range(2):
+            pool.submit(_order(seq, ent), 0.001 * seq)
+            seq += 1
+    assert _in_flight(pool) == [0, 1] and flight.waits == 0
+
+
+@pytest.mark.parametrize("barrier", ["flush", "reshard", "drain_to_depth",
+                                     "settle"])
+def test_every_barrier_leaves_nothing_in_flight(barrier):
+    pool, flight = _flight_pool()
+    out = []
+    for i in range(5):          # two flushes launched, one order queued
+        out.extend(pool.submit(_order(i), 0.001 * i))
+    assert _in_flight(pool) == [0] and len(pool) == 1
+    if barrier == "flush":
+        out.extend(pool.flush())
+    elif barrier == "reshard":
+        out.extend(pool.reshard(2))
+    elif barrier == "drain_to_depth":
+        got, admitted = pool.drain_to_depth(1, 0.005)
+        out.extend(got)
+        assert admitted
+    else:
+        pool.settle()
+        assert _in_flight(pool) == []
+        out.extend(pool.flush())
+    assert _in_flight(pool) == []
+    assert [r.request.seq for r in out] == list(range(5))
+    assert [r.score for r in out] == [float(i) for i in range(5)]
+    # a wait at each later launch (the second flush, the barrier's flush of
+    # the queued order) and one at the barrier itself, none else
+    assert pool.pool_stats["blocking_resolves"] == 3
+
+
+def test_the_process_backend_resolves_within_the_same_pass():
+    """A DeferredScore with no readiness check (the process backend's)
+    reads as ready, so the pool resolves it at the end of the call that
+    posted it: nothing is carried over, nothing waits."""
+    pool, flight = _flight_pool(controlled=False)
+    assert pool.submit(_order(0), 0.0) == []
+    out = pool.submit(_order(1), 0.001)
+    assert [r.request.seq for r in out] == [0, 1]
+    assert _in_flight(pool) == [] and flight.waits == 1
+    assert pool.pool_stats["deferred_flushes"] == 0
+    assert pool.pool_stats["blocking_resolves"] == 0
+
+
+def test_the_stage2_scorer_defers_the_read_and_scores_as_the_slot_path():
+    """``Stage2Scorer`` returns once stage 2 is launched; the deferred
+    scores equal, bit for bit, those of the path that finishes at once."""
+    cfg = LNNConfig(num_gnn_layers=2, hidden_dim=8, feat_dim=4, mlp_dims=(8,))
+    store = KVStore(dim=8)
+    rng = np.random.default_rng(0)
+    for e in range(6):
+        store.put(pack_key(e, 0), rng.standard_normal(8).astype(np.float32))
+    scorer = Stage2Scorer(lnn_init(jax.random.PRNGKey(1), cfg), cfg, store,
+                          k_max=4)
+    feats = rng.standard_normal((4, 4)).astype(np.float32)
+    lists = [[(e, 0), (e + 1, 0)] for e in range(4)]
+    d = scorer(feats, lists)
+    assert isinstance(d, DeferredScore)
+    probs, stale, version = d.wait()
+    assert d.ready()
+    emb, mask, st = store.lookup_batch_versioned(lists, 4)
+    ref, ref_stale, ref_version = scorer.score_slots(feats, lists, emb, mask,
+                                                     st)
+    assert probs.dtype == np.float32 and probs.tobytes() == ref.tobytes()
+    assert (stale == ref_stale).all() and version == ref_version == 0
+    raw = scorer._score(scorer.params, 0, scorer._stage2, False, feats,
+                        lists, emb, mask, st)
+    assert isinstance(raw[0], ScoresInFlight)
+    assert np.asarray(raw[0]).tobytes() == ref.tobytes()
