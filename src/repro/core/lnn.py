@@ -25,7 +25,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.graph import EdgeType, NodeType, PaddedGraph
-from repro.core.layers import LAYER_REGISTRY, _glorot, weighted_gather_sum
+from repro.core.layers import (
+    HGT_STAGE1_RELATIONS,
+    LAYER_REGISTRY,
+    _glorot,
+    hgt_attend,
+    hgt_slot_attend,
+    hgt_update,
+    weighted_gather_sum,
+)
 
 
 @dataclass(frozen=True)
@@ -40,7 +48,7 @@ class LNNConfig:
     structure, and numerics all bit-identical to the pre-hetero layout.
     """
 
-    gnn_type: str = "gcn"            # 'gcn' | 'gat' | 'sage'
+    gnn_type: str = "gcn"            # 'gcn' | 'gat' | 'sage' | 'hgt'
     num_gnn_layers: int = 3          # total GNN layers (>= 2: stage1 has L-1)
     hidden_dim: int = 64
     mlp_dims: tuple = (64, 32)
@@ -48,12 +56,19 @@ class LNNConfig:
     use_pallas: bool = False
     pos_weight: float = 1.0          # BCE positive-class weight (fraud is rare)
     entity_types: tuple = ()         # () = homogeneous; e.g. hetero.ENTITY_TYPE_NAMES
+    num_heads: int = 1               # attention heads (hgt); divides hidden_dim
 
     def __post_init__(self):
         if self.num_gnn_layers < 2:
             raise ValueError("LNN needs >= 2 GNN layers (stage1 >= 1, stage2 == 1)")
         if self.gnn_type not in LAYER_REGISTRY:
             raise ValueError(f"unknown gnn_type {self.gnn_type}")
+        if self.gnn_type == "hgt" and self.entity_types:
+            raise ValueError("hgt types its own linears; entity_types is "
+                             "for gcn, gat and sage")
+        if self.num_heads < 1 or self.hidden_dim % self.num_heads:
+            raise ValueError(f"num_heads {self.num_heads} must divide "
+                             f"hidden_dim {self.hidden_dim}")
         object.__setattr__(self, "entity_types", tuple(self.entity_types))
 
 
@@ -66,6 +81,8 @@ def lnn_init(rng, cfg: LNNConfig):
     when ``cfg.entity_types`` is non-empty.
     """
     init_fn, _ = LAYER_REGISTRY[cfg.gnn_type]
+    if cfg.gnn_type == "hgt":
+        init_fn = functools.partial(init_fn, num_heads=cfg.num_heads)
     n_base = cfg.num_gnn_layers + len(cfg.mlp_dims) + 3
     n_types = len(cfg.entity_types)
     keys = jax.random.split(rng, n_base)
@@ -169,8 +186,9 @@ def lnn_stage1(params, cfg: LNNConfig, graph: PaddedGraph):
         emb = params["typed"]["entity_type_emb"]
         h = h + (graph.tower >= 0)[:, None] * emb[jnp.clip(graph.tower, 0)]
     h = jax.nn.relu(h)
+    extra = {"relations": HGT_STAGE1_RELATIONS} if cfg.gnn_type == "hgt" else {}
     for layer in params["gnn"]:
-        h = apply_fn(layer, h, stage1_graph, cfg.use_pallas)
+        h = apply_fn(layer, h, stage1_graph, cfg.use_pallas, **extra)
     return h
 
 
@@ -186,8 +204,13 @@ def lnn_order_tower(params, cfg: LNNConfig, order_feats):
     h = h + params["type_emb"][NodeType.ORDER]
     h = jax.nn.relu(h)
     for layer in params["gnn"]:
-        # all three layer types share the self-transform form
-        h = jax.nn.relu(h @ layer["w_self"] + layer["b"])
+        if cfg.gnn_type == "hgt":
+            # HGT's update with an empty aggregate:
+            # LN_order(alpha * b_A + (1 - alpha) * h)
+            h = hgt_update(layer, jnp.zeros_like(h), h, NodeType.ORDER)
+        else:
+            # gcn, gat and sage share the self-transform form
+            h = jax.nn.relu(h @ layer["w_self"] + layer["b"])
     return h
 
 
@@ -202,6 +225,9 @@ def _last_layer_combine(params, cfg: LNNConfig, agg, self_h):
     ``self_h`` the node's own hidden state.
     """
     p = params["last"]
+    if cfg.gnn_type == "hgt":
+        # the target is an order: the order type's update, no ReLU
+        return hgt_update(p, agg, self_h, NodeType.ORDER)
     if cfg.gnn_type == "gcn":
         # orders only receive ENTITY_TO_ORDER edges; use that etype's weight
         out = self_h @ p["w_self"] + agg @ p["w_nbr"][EdgeType.ENTITY_TO_ORDER]
@@ -223,6 +249,9 @@ def _mlp(params, x):
 def _final_hop_aggregate(params, cfg: LNNConfig, h, graph: PaddedGraph):
     """Neighbor aggregate of the last layer, restricted to final-hop edges."""
     w_fin = graph.nbr_mask * (graph.nbr_etype == EdgeType.ENTITY_TO_ORDER)
+    if cfg.gnn_type == "hgt":
+        return hgt_attend(params["last"], h, graph._replace(nbr_mask=w_fin),
+                          relations=(EdgeType.ENTITY_TO_ORDER,))
     if cfg.gnn_type == "gcn" or cfg.gnn_type == "sage":
         cnt = jnp.maximum(w_fin.sum(-1, keepdims=True), 1.0)
         return weighted_gather_sum(h, graph.nbr_idx, w_fin / cnt, cfg.use_pallas)
@@ -258,7 +287,7 @@ def lnn_stage2_batch(params, cfg: LNNConfig, h, graph: PaddedGraph):
 
 @_f32
 def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
-                     order_h=None, slot_type=None):
+                     order_h=None, slot_type=None, slot_dt=None):
     """Online stage-2 *embedding*: everything up to (but excluding) the MLP
     head — the last GNN layer's output concatenated with the raw checkout
     features, ``[B, H + F]``.
@@ -268,7 +297,9 @@ def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
     exactly ``_mlp`` over the same tensor, so factoring it out changes no
     numerics.  ``slot_type``: optional [B, K] int type codes per entity
     slot (-1 = untyped/padding) — applies the per-type towers of a
-    heterogeneous model before aggregation.
+    heterogeneous model before aggregation.  ``slot_dt``: [B, K] int time
+    gaps per slot, the order's snapshot minus the served key's (HGT's
+    relative temporal encoding; required for ``gnn_type == "hgt"``).
     """
     if order_h is None:
         order_h = lnn_order_tower(params, cfg, order_feats)
@@ -277,6 +308,9 @@ def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
     if cfg.gnn_type in ("gcn", "sage"):
         cnt = jnp.maximum(emb_mask.sum(-1, keepdims=True), 1.0)
         agg = jnp.einsum("bkh,bk->bh", entity_emb, emb_mask / cnt)
+    elif cfg.gnn_type == "hgt":
+        agg = hgt_slot_attend(params["last"], order_h, entity_emb, emb_mask,
+                              slot_dt)
     else:  # gat
         p = params["last"]
         z = entity_emb @ p["w"]
@@ -292,7 +326,7 @@ def lnn_stage2_embed(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
 
 @_f32
 def lnn_stage2_online(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
-                      order_h=None, slot_type=None):
+                      order_h=None, slot_type=None, slot_dt=None):
     """Online scoring path: KV-fetched entity embeddings -> risk logit.
 
     entity_emb: [B, K, H] stage-1 embeddings of the ≤K linked effective
@@ -302,7 +336,8 @@ def lnn_stage2_online(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
     valid: stage 1 masks final-hop edges, so an order's stage-1 state is a
     pure function of its own raw features, see ``lnn_order_tower``).
     ``slot_type``: optional [B, K] int entity-type codes (heterogeneous
-    models; -1 = padding/untyped slot).
+    models; -1 = padding/untyped slot).  ``slot_dt``: [B, K] int time gap
+    per slot, the order's snapshot minus the served key's (``hgt`` only).
 
     With ``cfg.use_pallas`` the whole path — tower, masked aggregation,
     last-layer combine, MLP logit — runs as ONE fused Pallas launch
@@ -314,9 +349,9 @@ def lnn_stage2_online(params, cfg: LNNConfig, entity_emb, emb_mask, order_feats,
         from repro.kernels.ops import stage2_score
 
         return stage2_score(params, cfg.gnn_type, entity_emb, emb_mask,
-                            order_feats, slot_type=slot_type)
+                            order_feats, slot_type=slot_type, slot_dt=slot_dt)
     x = lnn_stage2_embed(params, cfg, entity_emb, emb_mask, order_feats,
-                         order_h=order_h, slot_type=slot_type)
+                         order_h=order_h, slot_type=slot_type, slot_dt=slot_dt)
     return _mlp(params, x)
 
 

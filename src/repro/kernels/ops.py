@@ -17,8 +17,14 @@ from repro.kernels.csr_spmm import csr_spmm_pallas
 from repro.kernels.edge_softmax import edge_softmax_agg_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.gqa_decode import gqa_decode_pallas
+from repro.kernels.hgt import hgt_edge_softmax_pallas
+from repro.kernels.hgt import hgt_relation_project as hgt_relation_project_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
-from repro.kernels.stage2_score import flatten_stage2_params, stage2_score_pallas
+from repro.kernels.stage2_score import (
+    flatten_stage2_params,
+    stage2_score_hgt_pallas,
+    stage2_score_pallas,
+)
 
 
 def _interpret() -> bool:
@@ -43,6 +49,16 @@ def edge_softmax_agg(z, s_src, s_dst, nbr_idx, nbr_mask, etype_bias,
                                    block_n=block_n, interpret=_interpret())
 
 
+def hgt_relation_project(h, w, b, block_n: int = 512):
+    return hgt_relation_project_pallas(h, w, b, block_n=block_n,
+                                       interpret=_interpret())
+
+
+def hgt_edge_softmax(q, ke, me, scale, mask, block_n: int = 32):
+    return hgt_edge_softmax_pallas(q, ke, me, scale, mask, block_n=block_n,
+                                   interpret=_interpret())
+
+
 def flash_attention(q, k, v, causal: bool = True, window: int | None = None,
                     block_q: int = 128, block_k: int = 128):
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
@@ -61,7 +77,7 @@ def ssd_scan(x, dt, a, b, c, d_skip=None, chunk: int = 128):
 
 
 def stage2_score(params, gnn_type, entity_emb, emb_mask, order_feats,
-                 block_b: int = 128, slot_type=None):
+                 block_b: int = 128, slot_type=None, slot_dt=None):
     """Fused speed-layer scoring: whole online stage-2 path in one launch.
 
     Takes the full ``lnn_init`` params pytree; the stage-2-relevant leaves
@@ -69,8 +85,15 @@ def stage2_score(params, gnn_type, entity_emb, emb_mask, order_feats,
     one stack, folded away under jit).  Heterogeneous params (``"typed"`` in
     the pytree) select the typed kernel variant: ``slot_type`` is the int32
     ``[B, K]`` entity-type code per slot (-1 = padding/untyped; defaults to
-    all -1 when omitted).  Returns logits [B].
+    all -1 when omitted).  ``gnn_type == "hgt"`` runs its own body with
+    ``slot_dt`` (int32 ``[B, K]``, the order's snapshot minus each served
+    key's).  Returns logits [B].
     """
+    if gnn_type == "hgt":
+        return stage2_score_hgt_pallas(
+            entity_emb, emb_mask, order_feats, slot_dt,
+            flatten_stage2_params(params, gnn_type), block_b=block_b,
+            interpret=_interpret())
     typed = "typed" in params
     flat = flatten_stage2_params(params, gnn_type)
     if typed and slot_type is None:

@@ -165,10 +165,13 @@ def flatten_stage2_params(params, gnn_type: str):
     Stage-1 self-transform layers stack into ``[L-1, H, H]`` (hidden width is
     constant), biases/embedding rows become ``[1, H]`` so every ref is >= 2-D,
     and the MLP's first weight splits at row H into the ``g_out`` block and
-    the raw-feature block.
+    the raw-feature block.  An ``hgt`` model has its own body and order:
+    :func:`flatten_hgt_stage2_params`.
     """
     from repro.core.graph import EdgeType, NodeType
 
+    if gnn_type == "hgt":
+        return flatten_hgt_stage2_params(params)
     h = params["last"]["w_self"].shape[0]
     flat = [
         params["input"]["w"],
@@ -252,3 +255,161 @@ def stage2_score_pallas(entity_emb, emb_mask, order_feats, flat,
         out_shape=jax.ShapeDtypeStruct((b,), jnp.float32),
         interpret=interpret,
     )(*data, *flat)
+
+
+# ---------------------------------------------------------------------------
+# HGT: its own body and launch, so the gcn / gat / sage ones stay as they are
+# ---------------------------------------------------------------------------
+
+def _make_hgt_stage2_kernel(n_tower: int, n_slots: int, n_mlp_extra: int):
+    """The ``hgt`` body: order tower (each stage-1 layer reduces to
+    ``LN(alpha * b_A + (1 - alpha) h)``: an order has no stage-1 in-edges),
+    RTE added to each slot's row, the final hop's multi-head attention over
+    the slots (relation ENTITY_TO_ORDER: keys and messages by the entity
+    type's K/M linear with W^ATT / W^MSG folded in, queries by the order
+    type's Q), the order type's gated, normalised update, and the MLP head.
+    The slot loop is static, as in the other bodies; per-head sums and the
+    head-to-channel broadcast are matmuls with a 0/1 indicator."""
+    from repro.core.layers import gelu, layer_norm
+
+    def kernel(*refs):
+        with jax.default_matmul_precision("highest"):
+            body(*refs)
+
+    def body(*refs):
+        emb_ref, mask_ref, feats_ref, base_ref = refs[0:4]
+        (w_in_ref, b_in_ref, type_ref, tc_ref, tk_ref, tg_ref, tb_ref,
+         rw_ref, rb_ref, wk_ref, bk_ref, wm_ref, bm_ref, wq_ref, bq_ref,
+         sc_ref, ind_ref, indt_ref, wa_ref, ba_ref, al_ref, g_ref,
+         b_ref) = refs[4:27]
+        mlp_refs = refs[27:-1]
+        out_ref = refs[-1]
+
+        emb = emb_ref[...].astype(jnp.float32)      # [bb, K, H]
+        mask = mask_ref[...].astype(jnp.float32)    # [bb, K]
+        feats = feats_ref[...].astype(jnp.float32)  # [bb, F]
+        bb, K, H = emb.shape
+
+        h = feats @ w_in_ref[...] + b_in_ref[...] + type_ref[...]
+        h = jnp.maximum(h, 0.0)
+        for li in range(n_tower):
+            h = layer_norm(tc_ref[li] + tk_ref[li] * h, tg_ref[li], tb_ref[li])
+
+        base = base_ref[...].reshape(bb * K, H)
+        hs = emb.reshape(bb * K, H) + base @ rw_ref[...] + rb_ref[...]
+        k = (hs @ wk_ref[...] + bk_ref[...]).reshape(bb, K, H)
+        m = (hs @ wm_ref[...] + bm_ref[...]).reshape(bb, K, H)
+        q = h @ wq_ref[...] + bq_ref[...]                         # [bb, H]
+        ind, sc = ind_ref[...], sc_ref[...]
+        logits = []
+        for s in range(n_slots):
+            lg = ((k[:, s, :] * q) @ ind) * sc                      # [bb, nh]
+            logits.append(jnp.where(mask[:, s:s + 1] > 0, lg, -1e30))
+        mx = logits[0]
+        for lg in logits[1:]:
+            mx = jnp.maximum(mx, lg)
+        es = [jnp.exp(lg - mx) for lg in logits]
+        tot = es[0]
+        for e in es[1:]:
+            tot = tot + e
+        agg = jnp.zeros((bb, H), jnp.float32)
+        for s in range(n_slots):
+            attn = es[s] / tot * mask[:, s:s + 1]
+            agg = agg + (attn @ indt_ref[...]) * m[:, s, :]
+        alpha = al_ref[...]
+        g = layer_norm(alpha * (gelu(agg) @ wa_ref[...] + ba_ref[...])
+                       + (1.0 - alpha) * h, g_ref[...], b_ref[...])
+
+        w0g_ref, w0f_ref, b0_ref = mlp_refs[0:3]
+        y = g @ w0g_ref[...] + feats @ w0f_ref[...] + b0_ref[...]
+        for i in range(n_mlp_extra):
+            y = (jnp.maximum(y, 0.0) @ mlp_refs[3 + 2 * i][...]
+                 + mlp_refs[4 + 2 * i][...])
+        out_ref[...] = y[:, 0].astype(out_ref.dtype)
+
+    return kernel
+
+
+def flatten_hgt_stage2_params(params):
+    """The ``hgt`` body's weights in its argument order: the order tower's
+    per-layer constants (``alpha * b_A``, ``1 - alpha``, gamma, beta of the
+    order type, each ``[L-1, H]``), the last layer's RTE linear, its K and M
+    linears of the entity type with W^ATT / W^MSG of ENTITY_TO_ORDER folded
+    in, the order type's Q, the logit scale ``mu / sqrt(d_k)``, the head
+    indicator and its transpose, the order type's A linear, gate, gamma and
+    beta, then the MLP head as in :func:`flatten_stage2_params`."""
+    from repro.core.graph import EdgeType, NodeType
+    from repro.core.layers import hgt_folded_kv, hgt_num_heads
+    from repro.kernels.hgt import head_indicator
+
+    o, r = NodeType.ORDER, EdgeType.ENTITY_TO_ORDER
+    p = params["last"]
+    h = p["rte"]["w"].shape[0]
+    nh = hgt_num_heads(p)
+
+    def alpha(lp):
+        return jax.nn.sigmoid(lp["skip"][o])
+
+    gnn = params["gnn"]
+    ones = jnp.ones((1, h), jnp.float32)
+    wk, bk, wm, bm = hgt_folded_kv(p, (r,))
+    ind = head_indicator(h, nh)
+    flat = [
+        params["input"]["w"],
+        params["input"]["b"][None, :],
+        params["type_emb"][o][None, :],
+        jnp.stack([alpha(lp) * lp["a"]["b"][o] for lp in gnn]),
+        jnp.stack([(1.0 - alpha(lp)) * ones[0] for lp in gnn]),
+        jnp.stack([lp["ln_g"][o] for lp in gnn]),
+        jnp.stack([lp["ln_b"][o] for lp in gnn]),
+        p["rte"]["w"], p["rte"]["b"][None, :],
+        wk[0], bk, wm[0], bm,
+        p["q"]["w"][o], p["q"]["b"][o][None, :],
+        (p["mu"][r] / jnp.sqrt(jnp.float32(h // nh)))[None, :],
+        ind, ind.T,
+        p["a"]["w"][o], p["a"]["b"][o][None, :],
+        alpha(p) * ones,
+        p["ln_g"][o][None, :], p["ln_b"][o][None, :],
+    ]
+    mlp = params["mlp"]
+    w0 = mlp[0]["w"]
+    flat += [w0[:h], w0[h:], mlp[0]["b"][None, :]]
+    for layer in mlp[1:]:
+        flat += [layer["w"], layer["b"][None, :]]
+    return tuple(flat)
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def stage2_score_hgt_pallas(entity_emb, emb_mask, order_feats, slot_dt, flat,
+                            block_b: int = 128, interpret: bool = True):
+    """Fused online stage-2 scoring of an HGT model: ``(emb [B,K,H],
+    mask [B,K], feats [B,F], slot_dt [B,K]) -> logits [B]``; ``flat`` from
+    :func:`flatten_hgt_stage2_params`.  The sinusoid ``Base(slot_dt)`` is
+    computed here, on the device, and rides into the kernel as a
+    ``[B, K, H]`` input."""
+    from repro.core.layers import rte_base
+
+    b, k, hdim = entity_emb.shape
+    f = order_feats.shape[1]
+    bb = _bucket_block(b, block_b)
+    n_tower = flat[3].shape[0]
+    n_mlp_extra = (len(flat) - 23 - 3) // 2
+
+    def _full(a):
+        nd = a.ndim
+        return pl.BlockSpec(a.shape, lambda i, _nd=nd: (0,) * _nd)
+
+    in_specs = [
+        pl.BlockSpec((bb, k, hdim), lambda i: (i, 0, 0)),
+        pl.BlockSpec((bb, k), lambda i: (i, 0)),
+        pl.BlockSpec((bb, f), lambda i: (i, 0)),
+        pl.BlockSpec((bb, k, hdim), lambda i: (i, 0, 0)),
+    ] + [_full(a) for a in flat]
+    return pl.pallas_call(
+        _make_hgt_stage2_kernel(n_tower, k, n_mlp_extra),
+        grid=(ceil_div(b, bb),),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
+        out_shape=jax.ShapeDtypeStruct((b,), jnp.float32),
+        interpret=interpret,
+    )(entity_emb, emb_mask, order_feats, rte_base(slot_dt, hdim), *flat)

@@ -104,8 +104,8 @@ class SpeedLayer:
 
     def __post_init__(self):
         self._stage2 = jax.jit(
-            lambda p, emb, mask, feats: lnn_stage2_online(
-                p, self.cfg, emb, mask, feats
+            lambda p, emb, mask, feats, dt=None: lnn_stage2_online(
+                p, self.cfg, emb, mask, feats, slot_dt=dt
             )
         )
 
@@ -128,8 +128,18 @@ class SpeedLayer:
             [pack_key(e, t) for (e, t) in r.entity_keys] for r in reqs
         ]
         emb, mask = self.store.lookup_batch(key_lists, self.k_max)
-        logits = self._stage2(self.params, jnp.asarray(emb), jnp.asarray(mask),
-                              feats)
+        args = (self.params, jnp.asarray(emb), jnp.asarray(mask), feats)
+        if self.cfg.gnn_type == "hgt":
+            from repro.stream.workers import slot_gaps
+
+            if any(r.entity_keys and r.snapshot < 0 for r in reqs):
+                raise ValueError("an hgt model needs ScoreRequest.snapshot")
+            # exact keys: a served slot is 0 snapshots stale
+            stale = np.where(mask > 0, 0, -1).astype(np.int32)
+            args += (slot_gaps([r.snapshot for r in reqs],
+                               [r.entity_keys for r in reqs], stale,
+                               self.k_max),)
+        logits = self._stage2(*args)
         return np.asarray(jax.nn.sigmoid(logits))
 
 

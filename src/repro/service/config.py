@@ -54,7 +54,7 @@ class ModelSection:
     """The LNN model — field-for-field mirror of ``core.lnn.LNNConfig`` so
     a service artifact fully determines the architecture."""
 
-    gnn_type: str = "gcn"            # 'gcn' | 'gat' | 'sage'
+    gnn_type: str = "gcn"            # 'gcn' | 'gat' | 'sage' | 'hgt'
     num_gnn_layers: int = 3
     hidden_dim: int = 64
     mlp_dims: tuple = (64, 32)
@@ -64,6 +64,7 @@ class ModelSection:
     # heterogeneous vocabulary (e.g. core.hetero.ENTITY_TYPE_NAMES); empty =
     # homogeneous model, no per-type towers, untagged entity ids accepted
     entity_types: tuple = ()
+    num_heads: int = 1               # attention heads (hgt)
 
     def __post_init__(self):
         # JSON round-trips tuples as lists; normalize back

@@ -577,6 +577,12 @@ class FraudService:
         shadow_params = self._models[version]
         hybrid = isinstance(shadow_params, HybridModel)
         jit = self._shadow_jits.get(version)
+        hgt = lnn.gnn_type == "hgt"
+        if jit is None and hgt:
+            stage2 = lnn_stage2_embed if hybrid else lnn_stage2_online
+            jit = jax.jit(lambda p, emb, mask, feats, st, dt: stage2(
+                p, lnn, emb, mask, feats, slot_type=st, slot_dt=dt))
+            self._shadow_jits[version] = jit
         if jit is None:
             if hybrid:
                 jit = jax.jit(
@@ -604,18 +610,25 @@ class FraudService:
         if self.mode == "streaming":
             # expected_model_version=None: shadow reads must not pollute the
             # production model_stale_reads counter
-            emb, mask, _ = self.store.lookup_batch_versioned(key_lists, k)
+            emb, mask, stale = self.store.lookup_batch_versioned(key_lists, k)
         else:
             from repro.serve.kvstore import pack_key
 
             packed = [[pack_key(e, t) for (e, t) in keys] for keys in key_lists]
             emb, mask = self.store.lookup_batch(packed, k)
+            stale = np.where(mask > 0, 0, -1).astype(np.int32)
+        args = (emb, mask, feats, st)
+        if hgt:
+            # the same per-slot gaps the primary Stage2Scorer derives
+            from repro.stream.workers import slot_gaps
+
+            snaps = np.zeros(b, np.int32)
+            snaps[:n] = [r.snapshot for r in requests]
+            args += (slot_gaps(snaps, key_lists, stale, k),)
         if hybrid:
-            x = np.asarray(jit(shadow_params.lnn_params, emb, mask, feats, st),
-                           np.float32)
+            x = np.asarray(jit(shadow_params.lnn_params, *args), np.float32)
             return shadow_params.gbdt.predict_proba(x).astype(np.float32)[:n]
-        logits = np.asarray(jit(shadow_params, emb, mask, feats, st),
-                            np.float64)
+        logits = np.asarray(jit(shadow_params, *args), np.float64)
         # host-side f64 sigmoid, matching Stage2Scorer exactly (bit-parity);
         # a strongly-perturbed canary can drive exp to +inf, which saturates
         # to prob 0.0 — well-defined, so the overflow warning is noise

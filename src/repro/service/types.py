@@ -26,7 +26,9 @@ class ScoreRequest:
     cold start).  ``arrival`` is the virtual arrival time the streaming
     scheduler queues on; batch-mode callers may leave it 0.  ``tag`` is a
     caller-opaque id (the engine stores the :class:`CheckoutEvent` there);
-    ``seq`` is the pool's submission-order reorder key.
+    ``seq`` is the pool's submission-order reorder key.  ``snapshot`` is the
+    order's own snapshot: a model with a relative temporal encoding
+    (``gnn_type="hgt"``) reads each slot's gap from it.
     """
 
     features: np.ndarray          # [F]
@@ -34,6 +36,7 @@ class ScoreRequest:
     arrival: float = 0.0          # virtual arrival time (s)
     tag: object = None            # caller-opaque id (e.g. CheckoutEvent)
     seq: int = -1                 # submission order (pool reorder key)
+    snapshot: int = -1            # the order's snapshot (-1: not given)
 
     @classmethod
     def from_legacy(cls, r: "ScoreRequest | dict") -> "ScoreRequest":

@@ -380,7 +380,7 @@ def _rebuild_requests(arr):
             features=feats,
             entity_keys=[(int(e), int(s)) for e, s in keys[i]],
             arrival=float(arr["rq_arrival"][i]),
-            tag=ev, seq=int(arr["rq_seq"][i]),
+            tag=ev, seq=int(arr["rq_seq"][i]), snapshot=ev.snapshot,
         )
         out.append((int(arr["rq_location"][i]), req))
     held = []

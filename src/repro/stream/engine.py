@@ -244,6 +244,7 @@ class StreamingEngine:
             entity_keys=ing.entity_keys,
             arrival=event.arrival,
             tag=event,
+            snapshot=int(event.snapshot),
         )
 
     def submit(self, event: CheckoutEvent) -> list[ScoredResult]:

@@ -53,6 +53,16 @@ def bucket_size(n: int, max_batch: int) -> int:
     return min(b, max_batch)
 
 
+class FlushKeys(list):
+    """A flush's per-request ``(entity, t_e)`` key lists, carrying the
+    orders' own snapshots (``order_snapshots``, int32 ``[B]``, 0 on padding
+    rows) for a scorer that reads them (``reads_order_snapshot``: HGT's
+    relative temporal encoding).  It is a list, so every other scorer reads
+    it as the plain key lists."""
+
+    order_snapshots: np.ndarray
+
+
 class DeferredScore:
     """A score_fn result still in flight.
 
@@ -146,6 +156,9 @@ class MicroBatcher:
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         self.score_fn = score_fn
+        # build FlushKeys only for a scorer that reads the orders' snapshots
+        self.passes_order_snapshots = bool(
+            getattr(score_fn, "reads_order_snapshot", False))
         self.max_batch = int(max_batch)
         self.max_wait_s = float(max_wait_s)
         # deadline scheduling clock, used whenever a caller does not supply
@@ -276,6 +289,11 @@ class MicroBatcher:
                 for i, r in enumerate(batch):
                     feats[i] = r.features
                     key_lists[i] = list(r.entity_keys)
+                if self.passes_order_snapshots:
+                    key_lists = FlushKeys(key_lists)
+                    snaps = np.zeros(b, np.int32)
+                    snaps[:n] = [r.snapshot for r in batch]
+                    key_lists.order_snapshots = snaps
             self.stats["padded_rows"] += b - n
 
             crashpoint.fire("flush.before_score")

@@ -61,7 +61,7 @@ from repro.serve.kvstore import (
     entity_shard,
     stable_shard,
 )
-from repro.stream.microbatch import DeferredScore
+from repro.stream.microbatch import DeferredScore, FlushKeys
 from repro.stream.workers import SpeedLayerWorker, Stage2Scorer, WorkerPool
 from repro.utils import crashpoint
 
@@ -246,6 +246,9 @@ class ShardServer:
         if self.scorer.model_version != version:
             self.scorer.set_model(self._params_by_version[version], version)
         key_lists = header["keys"]
+        if "order_t" in header:
+            key_lists = FlushKeys(key_lists)
+            key_lists.order_snapshots = np.asarray(header["order_t"], np.int32)
         feats = self._feats_of(header, sections)
         k_max = self.scorer.k_max
         b = len(key_lists)
@@ -954,6 +957,10 @@ class ProcessWorkerPool(WorkerPool):
         remote, remote_emb = self._resolve_remote(wid, kl, version)
         header = {"cmd": "score", "version": version, "keys": kl,
                   "remote": remote}
+        order_t = getattr(key_lists, "order_snapshots", None)
+        if order_t is not None:
+            # the orders' snapshots, for a scorer that encodes time gaps
+            header["order_t"] = [int(t) for t in order_t]
         secs = [("remote_emb", remote_emb)] if len(remote_emb) else []
         feats = np.ascontiguousarray(feats, "<f4")
         # the fault-injection harness arms "worker_kill": the k-th SCORE

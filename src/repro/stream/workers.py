@@ -111,6 +111,20 @@ class ShardRouter:
         return self._epoch
 
 
+def slot_gaps(order_snapshots, entity_t_lists: list, stale: np.ndarray,
+              k_max: int) -> np.ndarray:
+    """Per-slot time gaps ``[B, k_max]`` int32 for HGT's relative temporal
+    encoding: the order's snapshot minus the snapshot of the row served
+    (``t_e`` less the slot's staleness), 0 on an empty slot (staleness
+    -1)."""
+    key_t = np.zeros((len(entity_t_lists), k_max), np.int32)
+    for i, pairs in enumerate(entity_t_lists):
+        for j, (_ent, t) in enumerate(pairs[:k_max]):
+            key_t[i, j] = t
+    dt = np.asarray(order_snapshots, np.int32)[:, None] - (key_t - stale)
+    return np.where(stale >= 0, dt, 0).astype(np.int32)
+
+
 def _sigmoid(logits: np.ndarray) -> np.ndarray:
     # host-side f64 sigmoid, NOT jax.nn.sigmoid: XLA CPU's vectorized
     # exp rounds differently per array length (bucket 2 vs 4 diverge by
@@ -178,6 +192,9 @@ class Stage2Scorer:
         self.store = store
         self.k_max = int(k_max)
         self._typed = bool(cfg.entity_types)
+        # HGT encodes each slot's time gap: the micro-batcher hands this
+        # scorer the orders' snapshots (``microbatch.FlushKeys``)
+        self.reads_order_snapshot = cfg.gnn_type == "hgt"
         self._jits: dict[int, object] = {}
         self.set_model(params, model_version)
 
@@ -193,7 +210,13 @@ class Stage2Scorer:
         hybrid = isinstance(params, HybridModel)
         if version not in self._jits:
             cfg = self.cfg
-            if hybrid:
+            stage2 = lnn_stage2_embed if hybrid else lnn_stage2_online
+            if self.reads_order_snapshot:
+                self._jits[version] = jax.jit(
+                    lambda p, emb, mask, feats, st, dt: stage2(
+                        p, cfg, emb, mask, feats, slot_type=st, slot_dt=dt)
+                )
+            elif hybrid:
                 self._jits[version] = jax.jit(
                     lambda p, emb, mask, feats, st: lnn_stage2_embed(
                         p, cfg, emb, mask, feats, slot_type=st)
@@ -219,6 +242,20 @@ class Stage2Scorer:
             for j, (ent, _t) in enumerate(pairs[: self.k_max]):
                 st[i, j] = type_code_of(ent)
         return st
+
+    def _slot_dt(self, entity_t_lists: list, stale: np.ndarray) -> np.ndarray:
+        """:func:`slot_gaps` of a flush, under span ``s2.slot_dt``; the
+        orders' snapshots ride on ``entity_t_lists`` as
+        :class:`~repro.stream.microbatch.FlushKeys` (a flush with no served
+        slot, such as a warm-up's, needs none)."""
+        with spans.span("s2.slot_dt"):
+            order_t = getattr(entity_t_lists, "order_snapshots", None)
+            if order_t is None:
+                if (stale >= 0).any():
+                    raise ValueError("an hgt model scores slots only with the "
+                                     "orders' snapshots (FlushKeys)")
+                order_t = np.zeros(len(entity_t_lists), np.int32)
+            return slot_gaps(order_t, entity_t_lists, stale, self.k_max)
 
     def __call__(self, feats: np.ndarray, entity_t_lists: list):
         """One flush: the KV multi-get, then stage 2 launched and its
@@ -273,6 +310,12 @@ class Stage2Scorer:
                                 params.gbdt.predict_proba)
         else:
             lnn, dtype, head = params, np.float64, _sigmoid
+        if self.reads_order_snapshot:
+            dt = self._slot_dt(entity_t_lists, stale)
+            with spans.span("s2.launch"):
+                out = stage2(lnn, emb, mask, f, st, dt)
+                out.copy_to_host_async()
+            return ScoresInFlight(out, dtype, head), stale.max(axis=1), version
         with spans.span("s2.launch"):
             out = stage2(lnn, emb, mask, f, st)
             out.copy_to_host_async()

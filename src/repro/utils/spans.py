@@ -44,6 +44,7 @@ SPANS = (
     "batch.flush",         # MicroBatcher.flush, from the popped batch on
     "batch.assemble",      # the padded features and key lists
     "kv.lookup",           # KVStore.lookup_batch_versioned
+    "s2.slot_dt",          # per-slot time gaps (hgt models only)
     "s2.launch",           # the jitted stage-2 call, result left on device
     "s2.sync",             # waiting for it and the device-to-host copy
     "s2.tail",             # host sigmoid (or the GBDT head) and staleness

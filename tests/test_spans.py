@@ -108,8 +108,9 @@ def test_a_traced_streaming_run_emits_every_span_on_its_path(
     assert svc.stats().refreshes > 0
     svc.close()
     evs, meta = _host_events(tmp_path)
-    # the synchronous refresh on the inline backend reaches every span
-    assert {e[0] for e in evs} == set(spans.SPANS)
+    # the synchronous refresh on the inline backend reaches every span but
+    # the slot gaps, which only an hgt model builds (tests/test_hgt.py)
+    assert {e[0] for e in evs} == set(spans.SPANS) - {"s2.slot_dt"}
     by = {}
     for name, s, e in evs:
         by.setdefault(name, []).append((s, e))

@@ -19,13 +19,21 @@ from repro.core import LNNConfig, lnn_init
 from repro.core.hetero import ENTITY_TYPE_NAMES
 from repro.kernels.csr_spmm import csr_spmm_pallas
 from repro.kernels.edge_softmax import edge_softmax_agg_pallas
-from repro.kernels.stage2_score import flatten_stage2_params, stage2_score_pallas
+from repro.kernels.hgt import hgt_edge_softmax_pallas, hgt_relation_project
+from repro.kernels.stage2_score import (
+    flatten_hgt_stage2_params,
+    flatten_stage2_params,
+    stage2_score_hgt_pallas,
+    stage2_score_pallas,
+)
 
 # served widths: configs/lnn_fraud.SERVICE over the synthetic checkout
 # stream (12 raw features), k_max=8 slots, community_size=4096 stage-1
 # bins at max_deg=32
 H, K, F = 64, 8, 12
 N_STAGE1, D_STAGE1 = 4096, 32
+# the lnn-hgt configuration: HGT's published width and heads
+H_HGT, HEADS_HGT, F_HGT = 256, 8, 48
 
 
 @pytest.fixture(scope="module")
@@ -97,4 +105,43 @@ def test_edge_softmax_compiles_for_v5e(one_chip):
         _spec(one_chip, (N_STAGE1, H)), _spec(one_chip, (N_STAGE1,)),
         _spec(one_chip, (N_STAGE1,)), _spec(one_chip, tile, jnp.int32),
         _spec(one_chip, tile), _spec(one_chip, tile))
+    _assert_native(lowered)
+
+
+@pytest.mark.parametrize("bucket", [1, 2, 16])
+def test_stage2_score_hgt_compiles_for_v5e(one_chip, bucket):
+    cfg = LNNConfig(gnn_type="hgt", num_gnn_layers=3, hidden_dim=H_HGT,
+                    num_heads=HEADS_HGT, mlp_dims=(64, 32), feat_dim=F_HGT)
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(functools.partial(lnn_init, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+
+    def fused(p, emb, mask, feats, dt):
+        return stage2_score_hgt_pallas(emb, mask, feats, dt,
+                                       flatten_hgt_stage2_params(p),
+                                       interpret=False)
+
+    _assert_native(jax.jit(fused).lower(
+        params, _spec(one_chip, (bucket, K, H_HGT)),
+        _spec(one_chip, (bucket, K)), _spec(one_chip, (bucket, F_HGT)),
+        _spec(one_chip, (bucket, K), jnp.int32)))
+
+
+def test_hgt_relation_project_compiles_for_v5e(one_chip):
+    lowered = jax.jit(functools.partial(hgt_relation_project, interpret=False)
+                      ).lower(_spec(one_chip, (N_STAGE1, H_HGT)),
+                              _spec(one_chip, (6, H_HGT, H_HGT)),
+                              _spec(one_chip, (6, H_HGT)))
+    _assert_native(lowered)
+
+
+def test_hgt_edge_softmax_compiles_for_v5e(one_chip):
+    edge = (N_STAGE1, D_STAGE1, H_HGT)
+    lowered = jax.jit(
+        functools.partial(hgt_edge_softmax_pallas, interpret=False)).lower(
+        _spec(one_chip, (N_STAGE1, H_HGT)), _spec(one_chip, edge),
+        _spec(one_chip, edge),
+        _spec(one_chip, (N_STAGE1, D_STAGE1, HEADS_HGT)),
+        _spec(one_chip, (N_STAGE1, D_STAGE1)))
     _assert_native(lowered)
