@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from bisect import bisect_left
 from dataclasses import dataclass, field
+from itertools import chain
 
 import numpy as np
 
@@ -33,8 +34,10 @@ from repro.core.graph import COOGraph, EdgeType, NodeType
 from repro.core.hetero import type_codes_array
 
 
-def _tower_codes(n_nodes: int, entity_snap_ids: dict) -> np.ndarray | None:
-    """Per-node entity-type tower codes for a materialized DDS graph.
+def _tower_codes(n_nodes: int, pair_entities: np.ndarray) -> np.ndarray | None:
+    """Per-node entity-type tower codes for a materialized DDS graph whose
+    last ``len(pair_entities)`` nodes are its entity-snapshot vertices, with
+    ``pair_entities`` the entity of each.
 
     Returns ``None`` when no entity id carries a :mod:`repro.core.hetero`
     type tag — homogeneous graphs keep the exact pre-hetero COO layout
@@ -42,16 +45,11 @@ def _tower_codes(n_nodes: int, entity_snap_ids: dict) -> np.ndarray | None:
     Otherwise an int32 [n_nodes] array: the type code at each entity-
     snapshot vertex, ``-1`` for orders, shadows, and untagged entities.
     """
-    if not entity_snap_ids:
-        return None
-    ents = np.fromiter((pair[0] for pair in entity_snap_ids),
-                       np.int64, len(entity_snap_ids))
-    codes = type_codes_array(ents)
+    codes = type_codes_array(pair_entities)
     if not (codes >= 0).any():
         return None
     tower = np.full(n_nodes, -1, np.int32)
-    nids = np.fromiter(entity_snap_ids.values(), np.int64, len(entity_snap_ids))
-    tower[nids] = codes
+    tower[n_nodes - codes.size:] = codes
     return tower
 
 
@@ -209,7 +207,7 @@ def build_dds(
         snapshot=snapshot,
         label=label,
         label_mask=label_mask,
-        tower=_tower_codes(n_nodes, entity_snap_ids),
+        tower=_tower_codes(n_nodes, uniq_entity),
     )
     return DDSGraph(coo=coo, num_orders=n_ord, entity_snap_ids=entity_snap_ids, last_hop=last_hop)
 
@@ -359,9 +357,8 @@ class IncrementalDDSBuilder:
         in event order), so ``pad_graph`` output is identical.
         """
         n_ord = self.num_orders
-        entity_snap_ids = {
-            pair: 2 * n_ord + i for i, pair in enumerate(sorted(self._pair_seq))
-        }
+        pairs = sorted(self._pair_seq)
+        entity_snap_ids = {pair: 2 * n_ord + i for i, pair in enumerate(pairs)}
         src, dst, et = [], [], []
         for o, ent, t in self._shadow_edges:
             e_node = entity_snap_ids[(ent, t)]
@@ -405,7 +402,7 @@ class IncrementalDDSBuilder:
             snapshot=snapshot,
             label=label,
             label_mask=label_mask,
-            tower=_tower_codes(n_nodes, entity_snap_ids),
+            tower=_tower_codes(n_nodes, [e for e, _ in pairs]),
         )
         dds = DDSGraph(coo=coo, num_orders=n_ord, entity_snap_ids=entity_snap_ids,
                        last_hop=last_hop)
@@ -424,6 +421,9 @@ class IncrementalDDSBuilder:
         its full in-neighborhood at any GNN depth.
 
         Cost is O(touched orders + touched pairs) — never O(total stream).
+        The edges are built with array operations from the builder's state
+        at call time; Python touches each order and each pair once, never
+        each edge.
 
         Local node-id layout mirrors ``build()``: [0, n_sub) selected
         orders in arrival order, then shadows, then entity snapshots in
@@ -436,95 +436,122 @@ class IncrementalDDSBuilder:
         community-local stage-1 embeddings equal the whole-graph ones
         bit-for-bit.
         """
-        ents = {int(e) for e in entities}
-        touched = sorted({o for e in ents
-                          for o in self._entity_orders.get(e, ())})
-        for o in touched:
-            for e2 in self._order_entities[o]:
-                if e2 not in ents:
-                    raise ValueError(
-                        f"entity set is not component-closed: order {o} links "
-                        f"entity {e2} outside the set"
-                    )
-        n_sub = len(touched)
-        order_local = {o: i for i, o in enumerate(touched)}
-        pairs = sorted((e, t) for e in ents for t in self._active.get(e, ()))
-        entity_snap_ids = {p: 2 * n_sub + i for i, p in enumerate(pairs)}
+        ents = np.unique(np.fromiter(entities, np.int64))
+        ent_l = ents.tolist()
+        orders_of = [self._entity_orders.get(e, ()) for e in ent_l]
+        touched = np.unique(np.fromiter(chain.from_iterable(orders_of),
+                                        np.int64, sum(map(len, orders_of))))
+        touched_l = touched.tolist()
+        n_sub = len(touched_l)
+        # per-order entity lists, flattened; one row per (order, entity) link
+        links = [self._order_entities[o] for o in touched_l]
+        link_order = np.repeat(np.arange(n_sub),
+                               np.fromiter(map(len, links), np.int64, n_sub))
+        link_ent = np.fromiter(chain.from_iterable(links), np.int64,
+                               link_order.size)
+        inside = np.isin(link_ent, ents)
+        if not inside.all():
+            bad = int(np.argmin(inside))
+            raise ValueError(
+                f"entity set is not component-closed: order "
+                f"{touched_l[link_order[bad]]} links entity "
+                f"{int(link_ent[bad])} outside the set"
+            )
+        order_t = np.array([self._order_snapshot[o] for o in touched_l],
+                           np.int64)
 
-        src, dst, et = [], [], []
+        # entity-snapshot pairs in sorted (entity, t) order; pos is a pair's
+        # index within its entity's active-snapshot list
+        snaps = [self._active.get(e, ()) for e in ent_l]
+        n_snap = np.fromiter(map(len, snaps), np.int64, len(snaps))
+        pair_t = np.fromiter(chain.from_iterable(snaps), np.int64,
+                             int(n_snap.sum()))
+        n_pairs = pair_t.size
+        pair_rank = np.repeat(np.arange(ents.size), n_snap)
+        pos = np.arange(n_pairs) - (np.cumsum(n_snap) - n_snap)[pair_rank]
+        t0 = int(pair_t.min()) if n_pairs else 0
+        span = (int(pair_t.max()) - t0 + 1) if n_pairs else 1
+        pair_key = pair_rank * span + (pair_t - t0)     # sorted ascending
+        e_base = 2 * n_sub
+        pair_ent = np.repeat(ents, n_snap)
+        entity_snap_ids = dict(zip(zip(pair_ent.tolist(), pair_t.tolist()),
+                                   range(e_base, e_base + n_pairs)))
+
+        # each link's (entity, t of its order) pair; add_order activated it
+        link_pair = np.searchsorted(
+            pair_key, np.searchsorted(ents, link_ent) * span
+            + (order_t[link_order] - t0))
         # shadow <-> entity, in event order (ascending order id, per-order
-        # entity order preserved) — matches the filtered _shadow_edges list
-        for o in touched:
-            t = self._order_snapshot[o]
-            s_node = n_sub + order_local[o]
-            for ent in self._order_entities[o]:
-                e_node = entity_snap_ids[(ent, t)]
-                src.append(s_node); dst.append(e_node); et.append(EdgeType.SHADOW_TO_ENTITY)
-                src.append(e_node); dst.append(s_node); et.append(EdgeType.ENTITY_TO_SHADOW)
-        # entity history: reconstruct each activation's edges from the
-        # active-snapshot list (the state at activation time was the strict
-        # prefix, so snaps[:j] reproduces _hist_edges exactly); only
-        # per-destination order matters to pad_graph, so iterating entities
-        # sorted rather than in global activation order is equivalent
-        for ent in sorted(ents):
-            snaps = self._active.get(ent, [])
-            for j, t in enumerate(snaps):
-                cur = entity_snap_ids[(ent, t)]
-                src.append(cur); dst.append(cur); et.append(EdgeType.ENTITY_HIST)
-                if self.entity_history == "consecutive":
-                    past = snaps[j - 1 : j] if j > 0 else []
-                else:
-                    past = snaps[:j]
-                    if self.max_history is not None:
-                        past = past[-self.max_history:]
-                for tp in past:
-                    src.append(entity_snap_ids[(ent, tp)]); dst.append(cur)
-                    et.append(EdgeType.ENTITY_HIST)
-        # final hop: latest strictly-past active snapshot per linked entity.
-        # Recomputing against the *current* active list is exact — snapshots
-        # activated after the order are never strictly before it
-        last_hop: dict = {}
-        for o in touched:
-            t = self._order_snapshot[o]
-            lo = order_local[o]
-            for ent in self._order_entities[o]:
-                snaps = self._active[ent]
-                idx = bisect_left(snaps, t) - 1
-                if idx < 0:
-                    continue
-                t_e = snaps[idx]
-                e_node = entity_snap_ids[(ent, t_e)]
-                src.append(e_node); dst.append(lo); et.append(EdgeType.ENTITY_TO_ORDER)
-                last_hop.setdefault(lo, []).append((ent, t_e, e_node))
+        # entity order preserved)
+        shadow = n_sub + link_order
+        e_node = e_base + link_pair
+        # entity history: the state at each activation was the strict prefix
+        # of the active list, so its in-edges are the self-loop, then the
+        # n_past snapshots before it, ascending; n_past follows Python's
+        # slice semantics of snaps[:pos][-max_history:]
+        if self.entity_history == "consecutive":
+            n_past = np.minimum(pos, 1)
+        elif self.max_history is None:
+            n_past = pos
+        elif self.max_history > 0:
+            n_past = np.minimum(pos, self.max_history)
+        else:
+            n_past = np.maximum(pos + self.max_history, 0)
+        hist_dst = np.repeat(np.arange(n_pairs), n_past + 1)
+        k = np.arange(hist_dst.size) - np.repeat(np.cumsum(n_past + 1)
+                                                 - (n_past + 1), n_past + 1)
+        hist_src = np.where(k == 0, hist_dst,
+                            hist_dst - n_past[hist_dst] + k - 1)
+        # final hop: the latest strictly-past active snapshot of each link's
+        # entity, the pair just before its own.  Recomputing against the
+        # *current* active list is exact — snapshots activated after the
+        # order are never strictly before it
+        has_past = pos[link_pair] > 0
+        final_order = link_order[has_past]
+        final_src = e_node[has_past] - 1
 
-        n_nodes = 2 * n_sub + len(entity_snap_ids)
+        src = np.concatenate([shadow, e_node, e_base + hist_src, final_src])
+        dst = np.concatenate([e_node, shadow, e_base + hist_dst, final_order])
+        et = np.repeat(np.array([EdgeType.SHADOW_TO_ENTITY,
+                                 EdgeType.ENTITY_TO_SHADOW,
+                                 EdgeType.ENTITY_HIST,
+                                 EdgeType.ENTITY_TO_ORDER], np.int32),
+                       [link_order.size, link_order.size, hist_dst.size,
+                        final_order.size])
+
+        hops = list(zip(link_ent[has_past].tolist(),
+                        pair_t[final_src - e_base].tolist(),
+                        final_src.tolist()))
+        lo, first = np.unique(final_order, return_index=True)
+        last_hop = {o: hops[a:b] for o, a, b in
+                    zip(lo.tolist(), first.tolist(),
+                        first[1:].tolist() + [len(hops)])}
+
+        n_nodes = e_base + n_pairs
         features = np.zeros((n_nodes, self.feat_dim), np.float32)
+        if n_sub:
+            features[:n_sub] = np.concatenate(
+                [self._order_features[o] for o in touched_l]).reshape(n_sub, -1)
+            features[n_sub:e_base] = features[:n_sub]
         node_type = np.full(n_nodes, NodeType.ENTITY, np.int32)
         node_type[:n_sub] = NodeType.ORDER
-        node_type[n_sub : 2 * n_sub] = NodeType.SHADOW
-        snapshot = np.zeros(n_nodes, np.int32)
+        node_type[n_sub:e_base] = NodeType.SHADOW
+        snapshot = np.concatenate([order_t, order_t, pair_t]).astype(np.int32)
         label = np.zeros(n_nodes, np.float32)
+        label[:n_sub] = [self._labels[o] for o in touched_l]
         label_mask = np.zeros(n_nodes, np.float32)
         label_mask[:n_sub] = 1.0
-        for o in touched:
-            lo = order_local[o]
-            features[lo] = self._order_features[o]
-            features[n_sub + lo] = self._order_features[o]
-            snapshot[lo] = snapshot[n_sub + lo] = self._order_snapshot[o]
-            label[lo] = self._labels[o]
-        for (ent, t), nid in entity_snap_ids.items():
-            snapshot[nid] = t
         coo = COOGraph(
             num_nodes=n_nodes,
-            src=np.asarray(src, np.int64),
-            dst=np.asarray(dst, np.int64),
-            etype=np.asarray(et, np.int32),
+            src=src,
+            dst=dst,
+            etype=et,
             features=features,
             node_type=node_type,
             snapshot=snapshot,
             label=label,
             label_mask=label_mask,
-            tower=_tower_codes(n_nodes, entity_snap_ids),
+            tower=_tower_codes(n_nodes, pair_ent),
         )
         return DDSGraph(coo=coo, num_orders=n_sub,
                         entity_snap_ids=entity_snap_ids, last_hop=last_hop)

@@ -119,26 +119,24 @@ def pad_graph(
     nbr_mask = np.zeros((num_nodes, max_deg), np.float32)
     nbr_etype = np.zeros((num_nodes, max_deg), np.int32)
 
-    # sort edges by dst for grouped fill
-    order = np.argsort(g.dst, kind="stable")
-    src_s, dst_s, et_s = g.src[order], g.dst[order], g.etype[order]
-    starts = np.searchsorted(dst_s, np.arange(num_nodes), side="left")
-    ends = np.searchsorted(dst_s, np.arange(num_nodes), side="right")
-    snap = g.snapshot
-    for v in np.nonzero(ends > starts)[0]:
-        s, e = starts[v], ends[v]
-        srcs = src_s[s:e]
-        ets = et_s[s:e]
-        if e - s > max_deg:
-            if deg_cap_policy == "recent":
-                keep = np.argsort(-snap[srcs], kind="stable")[:max_deg]
-            else:
-                keep = np.arange(max_deg)
-            srcs, ets = srcs[keep], ets[keep]
-        k = srcs.size
-        nbr_idx[v, :k] = srcs
-        nbr_mask[v, :k] = 1.0
-        nbr_etype[v, :k] = ets
+    # Group edges by destination, keeping each destination's edge order
+    # (the contract with the DDS builders).  Only a destination over the cap
+    # under 'recent' reorders its edges: most recent source snapshot first,
+    # ties in edge order.
+    over = deg[g.dst] > max_deg
+    if deg_cap_policy == "recent" and over.any():
+        key = np.where(over, -np.asarray(g.snapshot, np.int64)[g.src], 0)
+        order = np.lexsort((key, g.dst))
+    else:
+        order = np.argsort(g.dst, kind="stable")
+    dst_s = g.dst[order]
+    # rank of each edge within its destination; the first max_deg are kept
+    rank = np.arange(dst_s.size) - (np.cumsum(deg) - deg)[dst_s]
+    keep = rank < max_deg
+    rows, cols = dst_s[keep], rank[keep]
+    nbr_idx[rows, cols] = g.src[order][keep]
+    nbr_mask[rows, cols] = 1.0
+    nbr_etype[rows, cols] = g.etype[order][keep]
 
     feat = np.zeros((num_nodes, g.features.shape[1]), np.float32)
     feat[:n_real] = g.features
